@@ -365,6 +365,9 @@ def run_ingest_benches(full: bool):
     env["PYTHONPATH"] = os.pathsep.join(
         [str(repo / "src")] + ([env["PYTHONPATH"]]
                                if env.get("PYTHONPATH") else []))
+    # the children only parse with NumPy, but importing repro.cachesim
+    # imports JAX: keep them off the chip this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
 
     def _child(code: str, *argv: str) -> dict:
         proc = subprocess.run([sys.executable, "-c", code, *argv],

@@ -187,9 +187,9 @@ class HocsTables(TablePlan):
 
 
 class DsPgmTables(TablePlan):
-    """CS_FNA / CS_FNO with the DS_PGM subroutine — the batched JAX path
-    (float64, bit-exact modulo the ~1e-12 near-tie caveat documented on
-    ``repro.core.batched.selection_tables``)."""
+    """CS_FNA / CS_FNO with the DS_PGM subroutine — the batched float64
+    NumPy mirror (bit-exact modulo the ~1e-12 near-tie caveat documented
+    on ``repro.core.batched.selection_tables``), evaluated on the host."""
 
     name = "ds_pgm"
 
@@ -204,23 +204,11 @@ class DsPgmTables(TablePlan):
         from repro.core.batched import selection_tables
         cfg = sim.cfg
         n = st.n
-        k = 1 << n
-        pi_mat, nu_mat = st.pi_v, st.nu_v
-        v_count = pi_mat.shape[0]
-        # pad V to a power-of-two bucket: XLA compiles per shape, and
-        # bucketing makes shapes recur across runs (padding rows are
-        # copies of the last version; their masks are discarded)
-        vpad = 1 << max(4, (v_count - 1).bit_length())
-        if vpad > v_count:
-            pi_mat = np.concatenate(
-                [pi_mat, np.repeat(pi_mat[-1:], vpad - v_count, 0)])
-            nu_mat = np.concatenate(
-                [nu_mat, np.repeat(nu_mat[-1:], vpad - v_count, 0)])
-        mask = selection_tables(list(cfg.costs), pi_mat, nu_mat,
+        mask = selection_tables(list(cfg.costs), st.pi_v, st.nu_v,
                                 cfg.miss_penalty,
-                                fno=(cfg.policy == "fno"))
+                                fno=(cfg.policy == "fno"), backend="numpy")
         pow2 = 1 << np.arange(n, dtype=np.int64)
-        return (mask.reshape(-1, n)[:v_count * k] @ pow2).astype(np.int64)
+        return (mask.reshape(-1, n) @ pow2).astype(np.int64)
 
 
 class ExhaustiveTables(TablePlan):
@@ -407,6 +395,27 @@ def _prefetch_hocs(system, cfgs, policies) -> None:
         system.plan_cache[key] = tab.reshape(-1)
 
 
+def ds_pgm_jobs(system, cfgs: Sequence, policies: Sequence[str]) -> list:
+    """The ds_pgm-family (cell, policy) table builds :func:`prefetch_tables`
+    stacks, in stacking order: ``[(cache key, costs, penalty, fno)]``,
+    one per distinct key not yet in ``system.plan_cache``."""
+    ds_plan = next(p for p in PROVIDERS if isinstance(p, DsPgmTables))
+    jobs = []
+    seen = set()
+    for cfg in cfgs:
+        for p in policies:
+            pcfg = dataclasses.replace(cfg, policy=p)
+            if not isinstance(plan_for(pcfg), DsPgmTables):
+                continue
+            key = ds_plan.cache_key(pcfg)
+            if key in system.plan_cache or key in seen:
+                continue
+            seen.add(key)
+            jobs.append((key, tuple(pcfg.costs),
+                         float(pcfg.miss_penalty), p == "fno"))
+    return jobs
+
+
 def prefetch_tables(system, cfgs: Sequence, policies: Sequence[str],
                     *, backend: str = "numpy", mesh=None) -> None:
     """Stack every stackable (cell, policy) table build of a decision-
@@ -431,20 +440,7 @@ def prefetch_tables(system, cfgs: Sequence, policies: Sequence[str],
     """
     _prefetch_exhaustive(system, cfgs, policies)
     _prefetch_hocs(system, cfgs, policies)
-    ds_plan = next(p for p in PROVIDERS if isinstance(p, DsPgmTables))
-    jobs = []                # (cache key, costs, penalty, fno)
-    seen = set()
-    for cfg in cfgs:
-        for p in policies:
-            pcfg = dataclasses.replace(cfg, policy=p)
-            if not isinstance(plan_for(pcfg), DsPgmTables):
-                continue
-            key = ds_plan.cache_key(pcfg)
-            if key in system.plan_cache or key in seen:
-                continue
-            seen.add(key)
-            jobs.append((key, tuple(pcfg.costs),
-                         float(pcfg.miss_penalty), p == "fno"))
+    jobs = ds_pgm_jobs(system, cfgs, policies)
     if not jobs:
         return
     if backend == "jax":

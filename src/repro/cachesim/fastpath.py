@@ -28,7 +28,7 @@ The engine therefore runs in phases:
   2. DECISION PLAN — by I2, a decision within a view version is a pure
      function of the n-bit indication pattern, so the whole run needs at
      most V * 2^n distinct selections.  HOW those are produced is the
-     provider registry of ``repro.cachesim.engine``: batched JAX DS_PGM
+     provider registry of ``repro.cachesim.engine``: batched NumPy DS_PGM
      tables, the exact HOCS mirror, the 2^n-subset enumeration, the
      generic scalar fallback, the segmented ``fna_cal`` replay, or the
      direct PI replay — ``plan_for(cfg)`` picks the first match, and

@@ -53,8 +53,9 @@ invariants:
      model-based policy's decision depends on the request ONLY through the
      n-bit indication pattern, so there are at most 2^n distinct
      selections per view version; the fast engine memoises the full
-     decision table per version (via the batched JAX ``ds_pgm_batched``
-     path) and turns per-request policy calls into table lookups.
+     decision table per version (via the batched float64 NumPy mirror
+     of ``ds_pgm_batched``) and turns per-request policy calls into
+     table lookups.
 
 ``fna_cal`` breaks I2 (its empirical EWMAs move on every probe outcome),
 but its decisions still change only when a drifting rho crosses a DS_PGM

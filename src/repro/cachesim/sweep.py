@@ -128,18 +128,33 @@ def _farm_sweeps(jobs, store, workers: int,
                  chunk_size: Optional[int] = None) -> None:
     """Run the phase-1 sweep jobs ``[(trace, cfg)]`` across a spawn-based
     process pool, persisting each into ``store``.  spawn (not fork): the
-    parent may hold a live XLA client, which is not fork-safe; workers
-    only run the NumPy sweep phase anyway."""
+    parent may hold a live XLA client, which is not fork-safe.
+
+    Workers only run the NumPy sweep phase, yet importing
+    ``repro.cachesim`` imports JAX, and a TPU belongs to one process at a
+    time: a worker that reached for it while the parent holds it would
+    fail or hang.  So the workers are born with ``JAX_PLATFORMS=cpu`` in
+    their environment (JAX reads it at import), which lets a parent that
+    drives the chip still farm sweeps."""
     import multiprocessing
+    import os
     from concurrent.futures import ProcessPoolExecutor
     ctx = multiprocessing.get_context("spawn")
     root = str(store.root)
-    with ProcessPoolExecutor(max_workers=min(workers, len(jobs)),
-                             mp_context=ctx) as pool:
-        futs = [pool.submit(_sweep_worker, root, trace, cfg, chunk_size)
-                for trace, cfg in jobs]
-        for f in futs:
-            f.result()      # propagate worker failures loudly
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"     # inherited by every spawn
+    try:
+        with ProcessPoolExecutor(max_workers=min(workers, len(jobs)),
+                                 mp_context=ctx) as pool:
+            futs = [pool.submit(_sweep_worker, root, trace, cfg, chunk_size)
+                    for trace, cfg in jobs]
+            for f in futs:
+                f.result()      # propagate worker failures loudly
+    finally:
+        if saved is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
 
 
 def run_grid(traces: Union[Mapping[str, np.ndarray], Sequence[str]],

@@ -14,7 +14,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 EPS = 1e-12
 
@@ -130,7 +129,7 @@ def selection_tables(costs, pi, nu, miss_penalty, *, fno: bool = False,
         allowed = np.tile(pat_bits.astype(bool), (v, 1)) if fno else None
         return rho_selection_tables(
             costs, rhos, miss_penalty, allowed=allowed).reshape(v, k, n)
-    with enable_x64():
+    with jax.enable_x64(True):
         mask = ds_pgm_batched(
             jnp.asarray(np.asarray(costs, np.float64)),
             jnp.asarray(rhos), float(miss_penalty),
@@ -150,10 +149,13 @@ def selection_tables_cells(costs_cells, pi, nu, penalties, fno_cells,
     view history — untouched, so the only thing that varies across its
     cells is the (costs, miss_penalty, CS_FNO) triple each row is
     evaluated under.  This stacks all C cells' (version x pattern) grids
-    into one ``ds_pgm_batched`` evaluation with per-row costs/penalties
-    (chunked to ``max_rows`` rows so the [rows, n] matrices stay
-    bounded).  Rows are evaluated independently, so cell c's slice is
-    bit-identical to a per-cell :func:`selection_tables` call.
+    into one :func:`rho_selection_tables` evaluation — the float64 NumPy
+    mirror of ``ds_pgm_batched`` — with per-row costs/penalties (chunked
+    to ``max_rows`` rows so the [rows, n] matrices stay bounded).  Rows
+    are evaluated independently, so cell c's slice is bit-identical to a
+    per-cell ``selection_tables(..., backend="numpy")`` call.  It runs on
+    the host whatever JAX's default device is: this is the oracle that
+    the jitted :func:`selection_tables_cells_jax` is checked against.
 
     ``costs_cells``: [C, n]; ``penalties``: [C]; ``fno_cells``: [C] bool.
     """
@@ -168,23 +170,19 @@ def selection_tables_cells(costs_cells, pi, nu, penalties, fno_cells,
     pat_bits = (np.arange(k)[:, None] >> np.arange(n)[None, :]) & 1   # [K,n]
     rhos = np.where(pat_bits[None, :, :] > 0,
                     pi[:, None, :], nu[:, None, :]).reshape(v * k, n)
-    pat_tiled = np.tile(pat_bits, (v, 1))                             # [V*K,n]
+    pat_tiled = np.tile(pat_bits > 0, (v, 1))                         # [V*K,n]
     ones = np.ones_like(pat_tiled)
     out = np.empty((c, v * k, n), dtype=bool)
     per_call = max(1, max_rows // (v * k))        # whole cells per chunk
-    with enable_x64():
-        for lo in range(0, c, per_call):
-            hi = min(lo + per_call, c)
-            cc = hi - lo
-            rows = np.tile(rhos, (cc, 1))
-            costs_rows = np.repeat(costs_cells[lo:hi], v * k, axis=0)
-            m_rows = np.repeat(penalties[lo:hi], v * k)
-            fno_rows = np.concatenate(
-                [pat_tiled if f else ones for f in fno_cells[lo:hi]])
-            mask = ds_pgm_batched(
-                jnp.asarray(costs_rows), jnp.asarray(rows),
-                jnp.asarray(m_rows), fno_mask=jnp.asarray(fno_rows))
-            out[lo:hi] = np.asarray(mask).reshape(cc, v * k, n)
+    for lo in range(0, c, per_call):
+        hi = min(lo + per_call, c)
+        cc = hi - lo
+        mask = rho_selection_tables(
+            np.repeat(costs_cells[lo:hi], v * k, axis=0),
+            np.tile(rhos, (cc, 1)), np.repeat(penalties[lo:hi], v * k),
+            allowed=np.concatenate(
+                [pat_tiled if f else ones for f in fno_cells[lo:hi]]))
+        out[lo:hi] = mask.reshape(cc, v * k, n)
     return out.reshape(c, v, k, n)
 
 
@@ -282,15 +280,31 @@ def selection_tables_cells_jax(costs_cells, pi, nu, penalties, fno_cells,
     the differential tests gate exact mask agreement away from it.
     """
     pi = np.atleast_2d(np.asarray(pi, np.float64))
-    nu = np.atleast_2d(np.asarray(nu, np.float64))
     v, n = pi.shape
     k = 1 << n
+    c = np.atleast_2d(np.asarray(costs_cells)).shape[0]
+    if c == 0:
+        return np.empty((0, v, k, n), dtype=bool)
+    with jax.enable_x64(True):
+        args = cells_tables_args(costs_cells, pi, nu, penalties, fno_cells,
+                                 mesh=mesh)
+        out = np.asarray(_cells_tables_kernel(*args))
+    return out[:c].reshape(c, v, k, n)
+
+
+def cells_tables_args(costs_cells, pi, nu, penalties, fno_cells, *,
+                      mesh=None) -> tuple:
+    """The device arguments of :func:`_cells_tables_kernel` for C >= 1
+    cells, as :func:`selection_tables_cells_jax` stages them: cells
+    deduplicated into (costs, fno) groups and, with a multi-device
+    ``mesh``, both cell axes sharded and (pi, nu) replicated.  Call under
+    ``jax.enable_x64(True)`` so the float64 arrays stay float64."""
+    pi = np.atleast_2d(np.asarray(pi, np.float64))
+    nu = np.atleast_2d(np.asarray(nu, np.float64))
     costs_cells = np.atleast_2d(np.asarray(costs_cells, np.float64))
     penalties = np.asarray(penalties, np.float64)
     fno_cells = np.asarray(fno_cells, bool)
     c = costs_cells.shape[0]
-    if c == 0:
-        return np.empty((0, v, k, n), dtype=bool)
     # dedupe the penalty-independent sort stage: one group per unique
     # (costs, fno) pair, each cell pointing at its group
     keys = [(cc.tobytes(), bool(f))
@@ -305,23 +319,14 @@ def selection_tables_cells_jax(costs_cells, pi, nu, penalties, fno_cells,
         first[group_idx[i]] = i
     costs_u = costs_cells[first]
     fno_u = fno_cells[first]
-    with enable_x64():
-        if mesh is not None and mesh.size > 1:
-            from repro.distributed.sharding import (
-                replicate_to_mesh, shard_cells)
-            (cu, fu), _ = shard_cells([costs_u, fno_u], mesh)
-            (gi, pp), _ = shard_cells([group_idx, penalties], mesh)
-            pi_d = replicate_to_mesh(pi, mesh)
-            nu_d = replicate_to_mesh(nu, mesh)
-        else:
-            cu = jnp.asarray(costs_u)
-            fu = jnp.asarray(fno_u)
-            gi = jnp.asarray(group_idx)
-            pp = jnp.asarray(penalties)
-            pi_d = jnp.asarray(pi)
-            nu_d = jnp.asarray(nu)
-        out = np.asarray(_cells_tables_kernel(cu, fu, gi, pp, pi_d, nu_d))
-    return out[:c].reshape(c, v, k, n)
+    if mesh is not None and mesh.size > 1:
+        from repro.distributed.sharding import replicate_to_mesh, shard_cells
+        (cu, fu), _ = shard_cells([costs_u, fno_u], mesh)
+        (gi, pp), _ = shard_cells([group_idx, penalties], mesh)
+        return (cu, fu, gi, pp,
+                replicate_to_mesh(pi, mesh), replicate_to_mesh(nu, mesh))
+    return tuple(jnp.asarray(a) for a in
+                 (costs_u, fno_u, group_idx, penalties, pi, nu))
 
 
 def rho_selection_tables(costs, rhos, miss_penalty, *, allowed=None
@@ -345,29 +350,31 @@ def rho_selection_tables(costs, rhos, miss_penalty, *, allowed=None
     ``ds_pgm_batched``'s ``fno_mask``: excluded caches sort last (key =
     inf), can never be picked (cost = inf kills every prefix containing
     one), and drop out of the exclusion product.
+
+    ``costs`` is [n] shared or [B, n] per row, ``miss_penalty`` a scalar
+    or [B] per row (a stacked batch of decision cells); every operation
+    is row-local, so a row's mask does not depend on the rest of the
+    batch.
     """
     rhos = np.asarray(rhos, np.float64)
     b, n = rhos.shape
-    costs = np.asarray(costs, np.float64)
-    M = float(miss_penalty)
+    costs_b = np.broadcast_to(np.asarray(costs, np.float64), (b, n))
+    m = np.broadcast_to(np.asarray(miss_penalty, np.float64), (b,))
     logr = np.log(np.clip(rhos, EPS, 1.0 - EPS))
-    key = costs[None, :] / -logr
+    key = costs_b / -logr
     if allowed is not None:
         allowed = np.asarray(allowed, bool)
         key = np.where(allowed, key, np.inf)        # excluded -> last
         logr = np.where(allowed, logr, 0.0)         # drop from the product
+        costs_b = np.where(allowed, costs_b, np.inf)
     order = np.argsort(key, axis=1, kind="stable")
     flat = order + (np.arange(b) * n)[:, None]      # row-flattened gather
-    if allowed is None:
-        csum = np.cumsum(costs[order], axis=1)
-    else:
-        costs_b = np.where(allowed, np.broadcast_to(costs, (b, n)), np.inf)
-        csum = np.cumsum(np.take_along_axis(costs_b, order, 1), axis=1)
+    csum = np.cumsum(np.take_along_axis(costs_b, order, 1), axis=1)
     lprod = np.cumsum(logr.reshape(-1)[flat], axis=1)
-    phi = csum + M * np.exp(lprod)                  # prefix costs, i = 1..n
+    phi = csum + m[:, None] * np.exp(lprod)         # prefix costs, i = 1..n
     best = np.argmin(phi, axis=1)
     # the empty prefix (cost M) wins ties, exactly like argmin over [M, phi]
-    take = np.where(phi[np.arange(b), best] < M, best + 1, 0)
+    take = np.where(phi[np.arange(b), best] < m, best + 1, 0)
     pick_sorted = np.arange(n)[None, :] < take[:, None]
     mask = np.empty((b, n), dtype=bool)
     mask.reshape(-1)[flat] = pick_sorted
@@ -656,7 +663,7 @@ def hocs_fna_batched(n_x, n, pi, nu, miss_penalty, *, backend: str = "numpy"
         np.asarray(pi, np.float64), np.asarray(nu, np.float64),
         np.asarray(miss_penalty, np.float64), n_x)
     if backend == "jax":
-        with enable_x64():
+        with jax.enable_x64(True):
             r0, r1 = _hocs_fna_jit(
                 jnp.asarray(n_x), jnp.asarray(pi), jnp.asarray(nu),
                 jnp.asarray(m), n=int(n))

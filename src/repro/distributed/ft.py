@@ -22,7 +22,7 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 class PreemptionHandler:
@@ -101,10 +101,6 @@ def elastic_mesh(model_dim: int = 1, devices=None):
     data = live // model_dim
     data = 2 ** int(math.log2(data)) if data > 0 else 1
     n = data * model_dim
-    try:
-        return jax.make_mesh((data, model_dim), ("data", "model"),
-                             devices=devices[:n])
-    except TypeError:
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices[:n]).reshape(data, model_dim),
-                    ("data", "model"))
+    return jax.make_mesh((data, model_dim), ("data", "model"),
+                         devices=devices[:n],
+                         axis_types=(AxisType.Auto, AxisType.Auto))

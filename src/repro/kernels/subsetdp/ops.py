@@ -15,16 +15,16 @@ IEEE operation chains; ``ref.py`` explains why, and why the final
   * ``"pallas"`` — the row-tiled kernel (``subsetdp.subset_prod_pallas``),
     interpret mode auto-selected off-TPU.
 
-Everything runs in float64 under ``enable_x64`` (the fast engine's
+Everything runs in float64 under ``jax.enable_x64`` (the fast engine's
 exactness contract); inputs/outputs are NumPy arrays so callers stay
-backend-agnostic.
+backend-agnostic.  A TPU has no float64, so there the ``"pallas"``
+backend raises rather than compile (see ``subsetdp.py``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.kernels.subsetdp.ref import subset_prod_ref
 from repro.kernels.subsetdp.subsetdp import (
@@ -58,7 +58,7 @@ def _pad_rows(rhos: np.ndarray, multiple: int) -> np.ndarray:
 def _prod(rhos: np.ndarray, miss_penalty: float, backend: str,
           row_block, interpret):
     """Device-side [B(+pad), 2^n] subset products for the jax/pallas
-    backends (call under ``enable_x64``)."""
+    backends (call under ``jax.enable_x64(True)``)."""
     if backend == "jax":
         return _subset_prod_ref_jit(jnp.asarray(rhos), miss_penalty)
     if backend == "pallas":
@@ -78,7 +78,7 @@ def subset_dp(costs, rhos, miss_penalty, *, backend: str = "pallas",
         from repro.core.batched import _subset_dp
         return _subset_dp(costs, rhos, miss_penalty)
     b, n = rhos.shape
-    with enable_x64():
+    with jax.enable_x64(True):
         prod = np.asarray(_prod(rhos, float(miss_penalty), backend,
                                 row_block, interpret))[:b]
     # final add OUTSIDE the jitted computation — same two roundings as the
@@ -120,7 +120,7 @@ def subset_argmin(costs, rhos, miss_penalty, *, allowed=None,
                    & ~np.asarray(allowed, np.int64)[:, None]) != 0
             phi[bad] = np.inf
         return np.argmin(phi, axis=1).astype(np.int64)
-    with enable_x64():
+    with jax.enable_x64(True):
         prod = _prod(rhos, float(miss_penalty), backend,
                      row_block, interpret)[:b]
         cost = jnp.asarray(_subset_costs(costs, n))

@@ -14,16 +14,20 @@ not share a jitted computation with the multiplies, or XLA contracts the
 pair into an FMA and the last ulp drifts off the oracle (``ref.py``
 documents the contraction hazard).
 
-Grid: (row_blocks,).  Block shapes:
-  mp    [1]               (miss penalty — an input, not a static, so one
-                           compilation serves a whole penalty sweep)
+Grid: (row_blocks,).  Block shapes (each meets the TPU's (8, 128)
+tiling: a multiple of it or the whole array dim):
+  mp    [1]               (SMEM scalar: the miss penalty — an input, not a
+                           static, so one compilation serves a whole
+                           penalty sweep)
   rhos  [RB, n]           (one row block)
   out   [RB, 2^n]         (subset products, M included)
 
-The table math is float64 (the fast engine's exactness contract), so the
-kernel is expected to run in interpret mode everywhere except TPU-class
-backends with native f64 — the same ``default_interpret()`` auto-selection
-as the Bloom kernel.
+The kernel runs in the dtype of ``rhos``.  TPUs have no float64: Mosaic
+refuses X64 element types, so compiled mode takes float32 only and a
+float64 call with ``interpret=False`` raises before reaching the
+compiler.  The fast engine's float64 exactness contract is therefore met
+in interpret mode (``default_interpret()`` picks it off-TPU) or by the
+``"jax"``/``"numpy"`` backends of ``ops.py``.
 """
 from __future__ import annotations
 
@@ -32,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: elements (RB * 2^n) per output block: bounds VMEM/working-set per tile
 DEFAULT_BLOCK_ELEMS = 1 << 16
@@ -53,9 +58,8 @@ def _subsetdp_kernel(mp_ref, rhos_ref, out_ref, *, n: int):
 
 
 def default_interpret() -> bool:
-    """Compiled only on TPU; interpret mode everywhere else (the table
-    math is float64 — see module docstring).  Pass ``interpret=False`` to
-    override."""
+    """Compiled only on TPU; interpret mode everywhere else.  Pass
+    ``interpret=False`` to override."""
     return jax.default_backend() != "tpu"
 
 
@@ -69,13 +73,18 @@ def default_row_block(n: int) -> int:
 def _subset_prod_jit(mp, rhos, *, n: int, row_block: int, interpret: bool):
     b = rhos.shape[0]
     assert b % row_block == 0, (b, row_block)
+    if not interpret and rhos.dtype == jnp.float64:
+        raise TypeError(
+            "subset-DP kernel: float64 cannot be compiled for a TPU — the "
+            "chip has no float64 (Mosaic rejects X64 element types); pass "
+            "float32 rows or run in interpret mode")
     k = 1 << n
     kernel = functools.partial(_subsetdp_kernel, n=n)
     return pl.pallas_call(
         kernel,
         grid=(b // row_block,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),                 # miss penalty
+            pl.BlockSpec(memory_space=pltpu.SMEM),              # miss penalty
             pl.BlockSpec((row_block, n), lambda i: (i, 0)),     # rho block
         ],
         out_specs=pl.BlockSpec((row_block, k), lambda i: (i, 0)),

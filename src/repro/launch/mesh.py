@@ -12,6 +12,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,12 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)}. "
             "Set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (dryrun.py does this for you).")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax without the devices kwarg
-        import numpy as np
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def data_axes(mesh) -> tuple:
@@ -53,9 +50,5 @@ def make_sweep_mesh(*, max_devices: int = None):
     n = len(devices)
     if n <= 1:
         return None
-    try:
-        return jax.make_mesh((n,), ("cells",), devices=devices)
-    except TypeError:  # older jax without the devices kwarg
-        import numpy as np
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices), ("cells",))
+    return jax.make_mesh((n,), ("cells",), devices=devices,
+                         axis_types=(AxisType.Auto,))

@@ -304,7 +304,6 @@ def _moe_shardmap(p, x, topi, topw, cfg: ModelConfig, rules):
     import math as _math
     from functools import partial as _partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -347,12 +346,12 @@ def _moe_shardmap(p, x, topi, topw, cfg: ModelConfig, rules):
                                     cap_override=cap)
         return out.reshape(bl, sl, d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, maxis, None), P(dp, maxis, None), P(dp, maxis, None),
                   P(maxis, None, None), P(maxis, None, None), P(maxis, None, None)),
         out_specs=P(dp, maxis, None),
-        check_rep=False,
+        check_vma=False,
     )
     cdt = x.dtype
     return fn(x, topi, topw, p["wg"].astype(cdt), p["wi"].astype(cdt),

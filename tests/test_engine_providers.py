@@ -145,8 +145,8 @@ def test_selection_tables_cells_bit_identical_to_per_cell():
         [c for c, _, _ in cells], pi, nu,
         [m for _, m, _ in cells], [f for _, _, f in cells])
     for i, (c, m, f) in enumerate(cells):
-        assert np.array_equal(stacked[i], selection_tables(c, pi, nu, m,
-                                                           fno=f)), i
+        assert np.array_equal(stacked[i], selection_tables(
+            c, pi, nu, m, fno=f, backend="numpy")), i
 
 
 def test_selection_tables_cells_chunked_matches_unchunked():

@@ -193,8 +193,8 @@ def test_ewma_path_matches_scalar_recurrence():
 def test_rho_selection_tables_matches_scalar_and_jax():
     """The NumPy float64 verification path agrees with both the scalar
     DS_PGM and the JAX batched path on random rho matrices."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.batched import ds_pgm_batched, rho_selection_tables
     from repro.core.policies import ds_pgm
@@ -207,7 +207,7 @@ def test_rho_selection_tables_matches_scalar_and_jax():
     for i in range(rhos.shape[0]):
         assert sorted(np.nonzero(mask[i])[0]) == \
             ds_pgm(costs, rhos[i].tolist(), m), i
-    with enable_x64():
+    with jax.enable_x64(True):
         jmask = np.asarray(ds_pgm_batched(
             jnp.asarray(np.asarray(costs, np.float64)),
             jnp.asarray(rhos), m))

@@ -76,11 +76,11 @@ def test_subset_dp_eager_ref_bit_exact():
     """The eager jnp mirror itself (no jit, no pallas) is bit-exact —
     pinning the ascending-sweep argument independently of the kernel
     plumbing."""
+    import jax
     rng = np.random.default_rng(7)
     costs, rhos, M = _instance(rng, 6, 19)
     ref = _subset_dp(costs, rhos, M)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(subset_dp_ref(costs, rhos, M))
     assert got.tobytes() == ref.tobytes()
 
@@ -306,11 +306,11 @@ def test_prefetch_jax_stacks_single_job():
 def test_ds_pgm_batched_all_ones_fno_mask_is_identity():
     """The cells kernel always passes a mask array (vmap needs one
     shape); an all-ones mask must therefore be an EXACT no-op."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(14)
     costs, rhos, M = _instance(rng, 5, 33)
-    with enable_x64():
+    with jax.enable_x64(True):
         plain = np.asarray(ds_pgm_batched(
             jnp.asarray(costs), jnp.asarray(rhos), M))
         masked = np.asarray(ds_pgm_batched(

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType
+AUTO = AxisType.Auto
 from functools import partial
 
 assert len(jax.devices()) == 8
@@ -31,7 +33,6 @@ from repro.distributed.sharding import param_shardings
 from repro.distributed.ft import elastic_mesh
 from repro.checkpoint import save, restore
 from repro.distributed.compression import compressed_psum
-from jax.experimental.shard_map import shard_map
 
 # ---- 1) sharded train step == single-device train step ----
 cfg = get_config("smollm-135m").reduced()
@@ -44,7 +45,7 @@ batch = make_concrete_batch(cfg, 4, 32, jax.random.PRNGKey(1))
 
 ref_state, ref_metrics = jax.jit(step)(jax.tree.map(jnp.copy, state), batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
 psh = param_shardings(mesh, params, cfg.tie_embeddings)
 state_sh = {"params": psh, "m": psh, "v": psh,
             "step": NamedSharding(mesh, P())}
@@ -78,14 +79,14 @@ with tempfile.TemporaryDirectory() as d:
 print("OK elastic reshard")
 
 # ---- 3) compressed int8 psum == float psum (within quant error) ----
-mesh1d = jax.make_mesh((8,), ("data",))
+mesh1d = jax.make_mesh((8,), ("data",), axis_types=(AUTO,))
 x = jax.random.normal(jax.random.PRNGKey(2), (8, 4096))
 
-@partial(shard_map, mesh=mesh1d, in_specs=P("data", None), out_specs=P("data", None))
+@partial(jax.shard_map, mesh=mesh1d, in_specs=P("data", None), out_specs=P("data", None))
 def f_comp(xl):
     return compressed_psum(xl[0], "data")[None]
 
-@partial(shard_map, mesh=mesh1d, in_specs=P("data", None), out_specs=P("data", None))
+@partial(jax.shard_map, mesh=mesh1d, in_specs=P("data", None), out_specs=P("data", None))
 def f_exact(xl):
     return jax.lax.psum(xl[0], "data")[None]
 
@@ -106,7 +107,7 @@ mparams = mm.init(jax.random.PRNGKey(3))
 mbatch = make_concrete_batch(cfgm, 4, 32, jax.random.PRNGKey(4))
 ref_loss, _ = jax.jit(mm.loss)(mparams, mbatch)   # no rules -> pure-jit path
 
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
 psh2 = param_shardings(mesh2, mparams, cfgm.tie_embeddings)
 bsh2 = {k: NamedSharding(mesh2, P("data", *([None] * (v.ndim - 1))))
         for k, v in mbatch.items()}

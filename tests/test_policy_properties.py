@@ -331,8 +331,8 @@ def test_rho_selection_tables_matches_ds_pgm_batched_x64(inst):
     the contract that lets the fast engine route any table build through
     either backend.  Checked with and without the CS_FNO candidate
     restriction (``allowed`` mask vs ``fno_mask``)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.batched import ds_pgm_batched, rho_selection_tables
     costs, rhos, allowed, M = inst
@@ -342,7 +342,7 @@ def test_rho_selection_tables_matches_ds_pgm_batched_x64(inst):
     costs_a = np.asarray(costs, np.float64)
     rhos_a = np.asarray(rhos, np.float64)
     allow_a = np.asarray(allowed, bool)
-    with enable_x64():
+    with jax.enable_x64(True):
         free = np.asarray(ds_pgm_batched(
             jnp.asarray(costs_a), jnp.asarray(rhos_a), float(M)))
         restricted = np.asarray(ds_pgm_batched(

@@ -212,13 +212,17 @@ def test_run_grid_workers_bit_identical_to_serial(tmp_path):
     assert store.stats["sweep_hits"] == 2
 
 
-def test_run_grid_workers_without_store_uses_ephemeral_root():
+def test_run_grid_workers_without_store_uses_ephemeral_root(monkeypatch):
     traces = {"gradle": get_trace("gradle", 5_000, seed=0)}
     base = SimConfig(engine="fast")
     serial = run_grid(traces, base, "update_interval", (100, 400),
                       policies=("fna",))
+    # workers are spawned with JAX pinned to the CPU; the parent's own
+    # environment is left as it was
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     parallel = run_grid(traces, base, "update_interval", (100, 400),
                         policies=("fna",), workers=2)
+    assert "JAX_PLATFORMS" not in os.environ
     _assert_grids_identical(parallel, serial)
 
 
