@@ -61,6 +61,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.batched import MAX_EXHAUSTIVE_TABLE_CACHES
 
 # 2^n table rows per version: past this the reference loop is the better
@@ -92,10 +93,14 @@ class DecisionPlan:
 
     def replay(self, sim, st, res):
         """Phase 2+3: produce per-request selections for ``sim`` against
-        the shared sweep ``st`` and fold them into ``res``."""
+        the shared sweep ``st`` and fold them into ``res``; spanned as
+        ``replay.<policy>`` and, beside it, ``replay.fold``."""
         from repro.cachesim.fastpath import accumulate_replay
-        return accumulate_replay(res, st, self.selections(sim, st),
-                                 list(sim.cfg.costs), sim.cfg.miss_penalty)
+        with obs.span(f"replay.{sim.cfg.policy}"):
+            selm = self.selections(sim, st)
+        with obs.span("replay.fold"):
+            return accumulate_replay(res, st, selm, list(sim.cfg.costs),
+                                     sim.cfg.miss_penalty)
 
 
 class TablePlan(DecisionPlan):
@@ -436,31 +441,32 @@ def prefetch_tables(system, cfgs: Sequence, policies: Sequence[str],
     tables on the one compiled path.  Masks can differ from the NumPy
     build only inside the ~1e-12 near-tie dead-band (FMA contraction;
     see ``selection_tables_cells_jax``).  The exhaustive/HOCS stacks
-    always evaluate on the NumPy oracle.
+    always evaluate on the NumPy oracle.  Spanned as ``tables``.
     """
-    _prefetch_exhaustive(system, cfgs, policies)
-    _prefetch_hocs(system, cfgs, policies)
-    jobs = ds_pgm_jobs(system, cfgs, policies)
-    if not jobs:
-        return
-    if backend == "jax":
-        from repro.core.batched import selection_tables_cells_jax
-        masks = selection_tables_cells_jax(
-            [j[1] for j in jobs], system.pi_v, system.nu_v,
-            [j[2] for j in jobs], [j[3] for j in jobs],
-            mesh=mesh)                                   # [C, V, 2^n, n]
-    else:
-        if len(jobs) < 2:    # a single build gains nothing from stacking
+    with obs.span("tables"):
+        _prefetch_exhaustive(system, cfgs, policies)
+        _prefetch_hocs(system, cfgs, policies)
+        jobs = ds_pgm_jobs(system, cfgs, policies)
+        if not jobs:
             return
-        from repro.core.batched import selection_tables_cells
-        masks = selection_tables_cells(
-            [j[1] for j in jobs], system.pi_v, system.nu_v,
-            [j[2] for j in jobs], [j[3] for j in jobs])  # [C, V, 2^n, n]
-    n = system.n
-    pow2 = 1 << np.arange(n, dtype=np.int64)
-    for (key, *_), mask in zip(jobs, masks):
-        system.plan_cache[key] = \
-            (mask.reshape(-1, n) @ pow2).astype(np.int64)
+        if backend == "jax":
+            from repro.core.batched import selection_tables_cells_jax
+            masks = selection_tables_cells_jax(
+                [j[1] for j in jobs], system.pi_v, system.nu_v,
+                [j[2] for j in jobs], [j[3] for j in jobs],
+                mesh=mesh)                               # [C, V, 2^n, n]
+        else:
+            if len(jobs) < 2:  # a single build gains nothing from stacking
+                return
+            from repro.core.batched import selection_tables_cells
+            masks = selection_tables_cells(
+                [j[1] for j in jobs], system.pi_v, system.nu_v,
+                [j[2] for j in jobs], [j[3] for j in jobs])  # [C,V,2^n,n]
+        n = system.n
+        pow2 = 1 << np.arange(n, dtype=np.int64)
+        for (key, *_), mask in zip(jobs, masks):
+            system.plan_cache[key] = \
+                (mask.reshape(-1, n) @ pow2).astype(np.int64)
 
 
 def run_cells(trace: np.ndarray, cfgs: Sequence, policies: Sequence[str],

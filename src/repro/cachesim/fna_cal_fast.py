@@ -56,10 +56,12 @@ calibration settings.
 """
 from __future__ import annotations
 
+import time
 from typing import List, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.cachesim.systemstate import SystemTrace
 from repro.core.batched import rho_exhaustive_tables, rho_selection_tables
 from repro.core.estimator import ewma_path
@@ -91,7 +93,16 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
     """[N] committed (post-exploration) selection bitmasks for fna_cal —
     the speculate/verify/bridge engine described in the module docstring,
     minus the cost fold.  Exposed separately so the topology layer can
-    re-account the same decisions under per-tier penalties."""
+    re-account the same decisions under per-tier penalties.
+
+    Adds to the ``fna_cal.*`` counters of :mod:`repro.obs` once per call:
+    ``requests`` (N), ``spec_committed`` (requests committed by
+    verification), ``verified_rows`` (rows passed to the verifier, the
+    aborted chunk's included), ``bridged`` (requests replayed by the
+    scalar bridge; ``spec_committed + bridged == requests``), and the
+    nanoseconds of the table builds (``build_ns``), the exact-state
+    trajectories (``trajectory_ns``), the verifier and its compare
+    (``verify_ns``) and the bridge (``bridge_ns``)."""
     cfg = sim.cfg
     n = st.n
     N = st.trace_len
@@ -141,13 +152,18 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
 
     selm = np.empty(N, dtype=np.int64)      # committed (post-eps) masks
 
+    clock = time.perf_counter_ns
+    build_ns = trajectory_ns = verify_ns = bridge_ns = 0
+    spec_committed = verified_rows = bridged = 0
+
     def bridge(s: int, count: int) -> Tuple[int, int]:
         """Reference-exact scalar replay of ``count`` requests from ``s``:
         per-request blend, scalar DS_PGM, exploration, probe feedback —
         the literal reference operations over the precomputed system
         arrays.  Mutates the calibration state in place; returns (end,
         pre-exploration mask of the last request) — the fresh table row."""
-        nonlocal pi_emp, nu_emp, pi_obs, nu_obs
+        nonlocal pi_emp, nu_emp, pi_obs, nu_obs, bridge_ns, bridged
+        t0 = clock()
         end = min(s + count, N)
         pe: List[float] = pi_emp.tolist()
         ne: List[float] = nu_emp.tolist()
@@ -190,6 +206,8 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
         nu_emp = np.asarray(ne, np.float64)
         pi_obs = np.asarray(po, np.int64)
         nu_obs = np.asarray(no, np.int64)
+        bridged += end - s
+        bridge_ns += clock() - t0
         return end, base
 
     def build_tables(vids) -> dict:
@@ -201,6 +219,8 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
         exactly, so speculation quality only improves; exactness is still
         owned by the verification pass and the scalar bridge."""
         from repro.core.batched import exhaustive_tables, selection_tables
+        nonlocal build_ns
+        t0 = clock()
         use_pi = pi_obs >= min_obs
         use_nu = nu_obs >= min_obs
         vids = [int(v) for v in vids]
@@ -213,6 +233,7 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
         else:
             tab = selection_tables(costs, rp, rn, M, backend="numpy")
             flat = (tab.reshape(-1, n) @ pow2).astype(np.int64)
+        build_ns += clock() - t0
         return {v: flat[i * k:(i + 1) * k] for i, v in enumerate(vids)}
 
     s = 0
@@ -256,6 +277,7 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
         commit = 0
         clean = True
         while commit < L and clean:
+            t0 = clock()
             c1 = min(commit + _CHUNK, L)
             cl = c1 - commit
             rows = slice(s + commit, s + c1)
@@ -298,10 +320,15 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
                 rho = np.where(ind_seg,
                                np.where(up_t, pi_t[:cl], st.pi_v[vc]),
                                np.where(un_t, nu_t[:cl], st.nu_v[vc]))
+            t1 = clock()
             true_selm = verify_fn(costs, rho, M) @ pow2
             bad = np.flatnonzero(true_selm != spec[commit:c1])
+            trajectory_ns += t1 - t0
+            verify_ns += clock() - t1
+            verified_rows += cl
             ok = cl if bad.size == 0 else int(bad[0])
             clean = bad.size == 0
+            spec_committed += ok
             selm[s + commit:s + commit + ok] = sel_spec[commit:commit + ok]
             pi_emp = pi_t[ok].copy()
             nu_emp = nu_t[ok].copy()
@@ -325,4 +352,10 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
             window = 0 if commit < _BURST_COMMIT \
                 else min(max(2 * commit, _SPEC_MIN_WINDOW), _MAX_WINDOW)
 
+    for name, value in (("requests", N), ("spec_committed", spec_committed),
+                        ("verified_rows", verified_rows),
+                        ("bridged", bridged), ("build_ns", build_ns),
+                        ("trajectory_ns", trajectory_ns),
+                        ("verify_ns", verify_ns), ("bridge_ns", bridge_ns)):
+        obs.add(f"fna_cal.{name}", value)
     return selm

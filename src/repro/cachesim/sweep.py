@@ -63,6 +63,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import obs
 from repro.cachesim.simulator import SimConfig, SimResult
 from repro.cachesim.systemstate import SystemTrace
 from repro.cachesim.traces import get_trace
@@ -196,6 +197,10 @@ def run_grid(traces: Union[Mapping[str, np.ndarray], Sequence[str]],
     ``chunk_size`` streams every phase-1 sweep (serial and farmed)
     through fixed-size trace slices — bit-identical results, bounded
     sweep working set (see ``SystemTrace.compute``).
+
+    A flat grid is spanned as ``grid.run``, each system group's
+    ``run_cells`` call as ``cells.group`` (``docs/engine.md``,
+    "Observability").
     """
     from repro.cachesim.engine import plan_for, run_cells
     from repro.cachesim.store import as_store
@@ -214,67 +219,71 @@ def run_grid(traces: Union[Mapping[str, np.ndarray], Sequence[str]],
                              policies=policies,
                              share_system=share_system, store=store,
                              chunk_size=chunk_size)
-    # classify cells by the policy-independent system key: cells of a
-    # decision-side axis all share one key (and thus ONE SystemTrace
-    # per trace); system-side cells each form their own group
-    per_trace: List[Tuple[str, np.ndarray, List[CellKey], Dict]] = []
-    for name, trace in traces.items():
-        order: List[CellKey] = []
-        groups: Dict[tuple, List[Tuple[CellKey, SimConfig]]] = {}
-        for value in values:
-            key = (name, cell_label(axis, value))
-            if key in order:
-                raise ValueError(
-                    f"duplicate grid cell {key!r}: two axis values share "
-                    f"the label {key[1]!r} — give mapping cells distinct "
-                    f"{axis!r} entries (or sweep a different axis)")
-            order.append(key)
-            cfg = dataclasses.replace(base, **cell_overrides(axis, value))
-            groups.setdefault(SystemTrace.system_key(cfg),
-                              []).append((key, cfg))
-        per_trace.append((name, trace, order, groups))
+    with obs.span("grid.run"):
+        # classify cells by the policy-independent system key: cells of a
+        # decision-side axis all share one key (and thus ONE SystemTrace
+        # per trace); system-side cells each form their own group
+        per_trace: List[Tuple[str, np.ndarray, List[CellKey], Dict]] = []
+        for name, trace in traces.items():
+            order: List[CellKey] = []
+            groups: Dict[tuple, List[Tuple[CellKey, SimConfig]]] = {}
+            for value in values:
+                key = (name, cell_label(axis, value))
+                if key in order:
+                    raise ValueError(
+                        f"duplicate grid cell {key!r}: two axis values share "
+                        f"the label {key[1]!r} — give mapping cells "
+                        f"distinct {axis!r} entries (or sweep a different "
+                        f"axis)")
+                order.append(key)
+                cfg = dataclasses.replace(base, **cell_overrides(axis, value))
+                groups.setdefault(SystemTrace.system_key(cfg),
+                                  []).append((key, cfg))
+            per_trace.append((name, trace, order, groups))
 
-    store = as_store(store)
-    tmp_root = None
-    try:
-        if workers > 1 and share_system:
-            if store is None:
-                # the hand-off needs SOME shared medium; scope it to the call
-                tmp_root = tempfile.mkdtemp(prefix="repro-store-")
-                store = as_store(tmp_root)
-            # one phase-1 job per (trace, group) whose sweep the serial
-            # pass below would compute and that isn't already stored
-            jobs = []
-            for name, trace, _, groups in per_trace:
-                tr = np.asarray(trace, dtype=np.uint64)
-                digest = store.trace_digest(tr)
-                for sys_key, cells in groups.items():
-                    cfgs = [cfg for _, cfg in cells]
-                    sweepable = all(cfg.engine == "fast" for cfg in cfgs) \
-                        and tr.shape[0] > 0 and any(
-                            plan_for(dataclasses.replace(cfg, policy=p))
-                            is not None for cfg in cfgs for p in policies)
-                    if sweepable and not store.has_sweep(digest, sys_key):
-                        jobs.append((tr, cfgs[0]))
-            if len(jobs) > 1:   # a 1-job farm is just spawn overhead
-                _farm_sweeps(jobs, store, workers, chunk_size=chunk_size)
+        store = as_store(store)
+        tmp_root = None
+        try:
+            if workers > 1 and share_system:
+                if store is None:
+                    # the hand-off needs SOME shared medium; scope it to
+                    # the call
+                    tmp_root = tempfile.mkdtemp(prefix="repro-store-")
+                    store = as_store(tmp_root)
+                # one phase-1 job per (trace, group) whose sweep the serial
+                # pass below would compute and that isn't already stored
+                jobs = []
+                for name, trace, _, groups in per_trace:
+                    tr = np.asarray(trace, dtype=np.uint64)
+                    digest = store.trace_digest(tr)
+                    for sys_key, cells in groups.items():
+                        cfgs = [cfg for _, cfg in cells]
+                        sweepable = all(cfg.engine == "fast" for cfg in cfgs) \
+                            and tr.shape[0] > 0 and any(
+                                plan_for(dataclasses.replace(cfg, policy=p))
+                                is not None for cfg in cfgs for p in policies)
+                        if sweepable and not store.has_sweep(digest, sys_key):
+                            jobs.append((tr, cfgs[0]))
+                if len(jobs) > 1:   # a 1-job farm is just spawn overhead
+                    _farm_sweeps(jobs, store, workers, chunk_size=chunk_size)
 
-        out: Dict[CellKey, Dict[str, SimResult]] = {}
-        for name, trace, order, groups in per_trace:
-            results: Dict[CellKey, Dict[str, SimResult]] = {}
-            for cells in groups.values():
-                group_out = run_cells(trace, [cfg for _, cfg in cells],
-                                      policies, share_system=share_system,
-                                      backend=backend, mesh=mesh,
-                                      store=store, chunk_size=chunk_size)
-                for (key, _), cell_res in zip(cells, group_out):
-                    results[key] = cell_res
-            for key in order:       # keep the caller's cell order
-                out[key] = results[key]
-        return out
-    finally:
-        if tmp_root is not None:
-            shutil.rmtree(tmp_root, ignore_errors=True)
+            out: Dict[CellKey, Dict[str, SimResult]] = {}
+            for name, trace, order, groups in per_trace:
+                results: Dict[CellKey, Dict[str, SimResult]] = {}
+                for cells in groups.values():
+                    with obs.span("cells.group"):
+                        group_out = run_cells(
+                            trace, [cfg for _, cfg in cells], policies,
+                            share_system=share_system, backend=backend,
+                            mesh=mesh, store=store, chunk_size=chunk_size)
+                    for (key, _), cell_res in zip(cells, group_out):
+                        results[key] = cell_res
+                for key in order:       # keep the caller's cell order
+                    out[key] = results[key]
+            return out
+        finally:
+            if tmp_root is not None:
+                shutil.rmtree(tmp_root, ignore_errors=True)
 
 
 def run_sweep(traces: Union[Mapping[str, np.ndarray], Sequence[str]],

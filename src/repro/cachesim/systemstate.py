@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import hash_indices
 from repro.cachesim.advert import (advert_cost, refill, resolve_advert,
                                    self_adjusting_decision)
@@ -402,77 +403,81 @@ class SystemTrace:
         per-request OUTPUT arrays by preallocated ``.npy`` memmaps filled
         chunk-by-chunk, bounding peak RSS at O(chunk + cache state); the
         memmaps are ordinary ndarrays to every consumer.  The caller owns
-        the spill directory's lifetime."""
+        the spill directory's lifetime.  Spanned as ``sweep``, with a
+        ``sweep.lru`` and a ``sweep.cbf_walk`` span per cache per chunk."""
         global SWEEPS_COMPUTED
-        SWEEPS_COMPUTED += 1
-        n = sim.cfg.n_caches
-        nodes = sim.nodes
-        N = int(trace.shape[0])
-        fresh = _is_fresh(sim)
-        if chunk_size is not None:
-            # same contract as iter_trace_chunks: reject early, by name
-            from repro.cachesim.tracefiles import validate_chunk_size
-            validate_chunk_size(chunk_size)
-        step = N if chunk_size is None else min(int(chunk_size), N)
+        with obs.span("sweep"):
+            SWEEPS_COMPUTED += 1
+            n = sim.cfg.n_caches
+            nodes = sim.nodes
+            N = int(trace.shape[0])
+            fresh = _is_fresh(sim)
+            if chunk_size is not None:
+                # same contract as iter_trace_chunks: reject early, by name
+                from repro.cachesim.tracefiles import validate_chunk_size
+                validate_chunk_size(chunk_size)
+            step = N if chunk_size is None else min(int(chunk_size), N)
 
-        # view inputs at entry — events below record every later change
-        fp0 = [nd.ind.fp_est for nd in nodes]
-        fn0 = [nd.ind.fn_est for nd in nodes]
-        q0 = [qe.q for qe in sim.q_est]
+            # view inputs at entry — events below record every later change
+            fp0 = [nd.ind.fp_est for nd in nodes]
+            fn0 = [nd.ind.fn_est for nd in nodes]
+            q0 = [qe.q for qe in sim.q_est]
 
-        ind_all, in_dj, dj_all, pats, ver_per_req = _alloc_outputs(
-            N, n, spill)
-        events: List[Tuple] = []
-        cnt_carry: List = [None] * n        # int32 CBF working counters
-        pow2 = 1 << np.arange(n, dtype=np.int64)
-        # indicator-quality measurement on the designated cache (Fig. 1)
-        quality = {"fn_events": 0, "fn_opportunities": 0, "fp_events": 0,
-                   "fp_opportunities": 0, "resident": 0}
-        start = 0
-        while start < N:
-            stop = min(start + step, N)
-            nc = stop - start
-            tchunk = trace[start:stop]
-            last = stop == N
-            dj_all[start:stop] = djc = sim._designated_batch(tchunk)
-            ind_c = ind_all[start:stop]
-            in_dj_c = in_dj[start:stop]
-            for j, nd in enumerate(nodes):
-                pos = np.flatnonzero(djc == j)
-                idx_j = hash_indices(tchunk, nd.ind.cbf.k, nd.ind.cbf.m,
-                                     nd.ind.cbf.seed)
-                mem, ins_gpos, evict_keys, evict_iidx = _lru_sweep(
-                    nd.lru, tchunk, pos)
-                in_dj_c[pos] = mem
-                cnt_carry[j] = _cbf_event_walk(
-                    nd, j, idx_j, ins_gpos, evict_keys, evict_iidx,
-                    ind_c, events, nc,
-                    base=start, cnt=cnt_carry[j], finalize=last)
-                id_ = ind_c[pos, j]
-                held = int(np.count_nonzero(mem))
-                quality["fn_opportunities"] += held
-                quality["resident"] += held
-                quality["fn_events"] += int(np.count_nonzero(mem & ~id_))
-                quality["fp_opportunities"] += int(pos.shape[0]) - held
-                quality["fp_events"] += int(np.count_nonzero(~mem & id_))
-            events.extend(_q_epoch_walk(sim.q_est, ind_c, nc, base=start))
-            pats[start:stop] = ind_c @ pow2
-            start = stop
+            ind_all, in_dj, dj_all, pats, ver_per_req = _alloc_outputs(
+                N, n, spill)
+            events: List[Tuple] = []
+            cnt_carry: List = [None] * n        # int32 CBF working counters
+            pow2 = 1 << np.arange(n, dtype=np.int64)
+            # indicator-quality measurement on the designated cache (Fig. 1)
+            quality = {"fn_events": 0, "fn_opportunities": 0, "fp_events": 0,
+                       "fp_opportunities": 0, "resident": 0}
+            start = 0
+            while start < N:
+                stop = min(start + step, N)
+                nc = stop - start
+                tchunk = trace[start:stop]
+                last = stop == N
+                dj_all[start:stop] = djc = sim._designated_batch(tchunk)
+                ind_c = ind_all[start:stop]
+                in_dj_c = in_dj[start:stop]
+                for j, nd in enumerate(nodes):
+                    pos = np.flatnonzero(djc == j)
+                    idx_j = hash_indices(tchunk, nd.ind.cbf.k, nd.ind.cbf.m,
+                                         nd.ind.cbf.seed)
+                    with obs.span("sweep.lru"):
+                        mem, ins_gpos, evict_keys, evict_iidx = _lru_sweep(
+                            nd.lru, tchunk, pos)
+                    in_dj_c[pos] = mem
+                    with obs.span("sweep.cbf_walk"):
+                        cnt_carry[j] = _cbf_event_walk(
+                            nd, j, idx_j, ins_gpos, evict_keys, evict_iidx,
+                            ind_c, events, nc,
+                            base=start, cnt=cnt_carry[j], finalize=last)
+                    id_ = ind_c[pos, j]
+                    held = int(np.count_nonzero(mem))
+                    quality["fn_opportunities"] += held
+                    quality["resident"] += held
+                    quality["fn_events"] += int(np.count_nonzero(mem & ~id_))
+                    quality["fp_opportunities"] += int(pos.shape[0]) - held
+                    quality["fp_events"] += int(np.count_nonzero(~mem & id_))
+                events.extend(_q_epoch_walk(sim.q_est, ind_c, nc, base=start))
+                pats[start:stop] = ind_c @ pow2
+                start = stop
 
-        pi_v, nu_v, fp_v, fn_v, points = _assemble_versions(
-            n, fp0, fn0, q0, events, N)
-        for i, (s0, vid) in enumerate(points):
-            s1 = points[i + 1][0] if i + 1 < len(points) else N
-            ver_per_req[s0:s1] = vid
+            pi_v, nu_v, fp_v, fn_v, points = _assemble_versions(
+                n, fp0, fn0, q0, events, N)
+            for i, (s0, vid) in enumerate(points):
+                s1 = points[i + 1][0] if i + 1 < len(points) else N
+                ver_per_req[s0:s1] = vid
 
-        return cls(
-            key=cls.system_key(sim.cfg), n=n, trace_len=N,
-            ind_all=ind_all, in_dj=in_dj, dj_all=dj_all, pats=pats,
-            ver_per_req=ver_per_req,
-            pi_v=pi_v, nu_v=nu_v, fp_v=fp_v, fn_v=fn_v,
-            quality=quality,
-            final_state=cls._snapshot(sim),
-            from_fresh=fresh, _trace=trace)
+            return cls(
+                key=cls.system_key(sim.cfg), n=n, trace_len=N,
+                ind_all=ind_all, in_dj=in_dj, dj_all=dj_all, pats=pats,
+                ver_per_req=ver_per_req,
+                pi_v=pi_v, nu_v=nu_v, fp_v=fp_v, fn_v=fn_v,
+                quality=quality,
+                final_state=cls._snapshot(sim),
+                from_fresh=fresh, _trace=trace)
 
     @staticmethod
     def _snapshot(sim) -> dict:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 EPS = 1e-12
 
 # The exhaustive dispatch tiers (single source of truth for the fast
@@ -278,6 +280,9 @@ def selection_tables_cells_jax(costs_cells, pi, nu, penalties, fno_cells,
     prefix costs tie to within that ulp — inside the same ~1e-12
     near-tie dead-band already documented on :func:`selection_tables`;
     the differential tests gate exact mask agreement away from it.
+
+    The kernel's call, from dispatch to the host array, is spanned as
+    ``tables.device``.
     """
     pi = np.atleast_2d(np.asarray(pi, np.float64))
     v, n = pi.shape
@@ -288,7 +293,8 @@ def selection_tables_cells_jax(costs_cells, pi, nu, penalties, fno_cells,
     with jax.enable_x64(True):
         args = cells_tables_args(costs_cells, pi, nu, penalties, fno_cells,
                                  mesh=mesh)
-        out = np.asarray(_cells_tables_kernel(*args))
+        with obs.span("tables.device"):
+            out = np.asarray(_cells_tables_kernel(*args))
     return out[:c].reshape(c, v, k, n)
 
 
