@@ -149,9 +149,32 @@ class FnaCalSegmented(DecisionPlan):
         return cfg.alg != "exhaustive" or \
             cfg.n_caches <= MAX_EXHAUSTIVE_TABLE_CACHES
 
+    @staticmethod
+    def lane_key(cfg) -> tuple:
+        """The settings one fna_cal replay reads beyond the shared sweep."""
+        return (cfg.alg, tuple(cfg.costs), float(cfg.miss_penalty),
+                float(cfg.cal_gamma), int(cfg.cal_min_obs),
+                float(cfg.cal_epsilon))
+
     def selections(self, sim, st) -> np.ndarray:
-        from repro.cachesim.fna_cal_fast import fna_cal_selections
-        return fna_cal_selections(sim, st)
+        """The cell's masks.  When :func:`run_cells` registered the
+        group's fna_cal cells on ``st``, the first of them replays every
+        registered cell of its ``alg`` in lockstep
+        (``fna_cal_fast.fna_cal_lanes``) and leaves the others' masks on
+        ``st.fna_cal_selections``, which each later cell takes; any other
+        call replays its cell alone."""
+        from repro.cachesim.fna_cal_fast import (fna_cal_lanes,
+                                                 fna_cal_selections)
+        key = self.lane_key(sim.cfg)
+        done = st.fna_cal_selections.pop(key, None)
+        if done is not None:
+            return done
+        if key not in st.fna_cal_lanes:
+            return fna_cal_selections(sim, st)
+        keys = [kk for kk in st.fna_cal_lanes if kk[0] == key[0]]
+        masks = fna_cal_lanes([st.fna_cal_lanes.pop(kk) for kk in keys], st)
+        st.fna_cal_selections.update(zip(keys, masks))
+        return st.fna_cal_selections.pop(key)
 
 
 class PiReplay(DecisionPlan):
@@ -469,6 +492,18 @@ def prefetch_tables(system, cfgs: Sequence, policies: Sequence[str],
                 (mask.reshape(-1, n) @ pow2).astype(np.int64)
 
 
+def _register_fna_cal(system, cfgs, policies) -> None:
+    """Register the group's fna_cal cells on the shared sweep, so the
+    first of them replays them all in lockstep (see
+    :meth:`FnaCalSegmented.selections`)."""
+    for cfg in cfgs:
+        for p in policies:
+            pcfg = dataclasses.replace(cfg, policy=p)
+            plan = plan_for(pcfg)
+            if isinstance(plan, FnaCalSegmented):
+                system.fna_cal_lanes.setdefault(plan.lane_key(pcfg), pcfg)
+
+
 def run_cells(trace: np.ndarray, cfgs: Sequence, policies: Sequence[str],
               share_system: bool = True, *, backend: str = "numpy",
               mesh=None, store=None, chunk_size: Optional[int] = None,
@@ -545,6 +580,7 @@ def run_cells(trace: np.ndarray, cfgs: Sequence, policies: Sequence[str],
                         preloaded.add(key)
             prefetch_tables(system, cfgs, policies,
                             backend=backend, mesh=mesh)
+            _register_fna_cal(system, cfgs, policies)
     for ci, cfg in enumerate(cfgs):
         for p in policies:
             sim = Simulator(dataclasses.replace(cfg, policy=p))
@@ -553,6 +589,9 @@ def run_cells(trace: np.ndarray, cfgs: Sequence, policies: Sequence[str],
                                  chunk_size=chunk_size)
             if share_system and system is None:
                 system = getattr(sim, "last_system", None)
+    if system is not None:             # the hand-over ends with the group
+        system.fna_cal_lanes.clear()
+        system.fna_cal_selections.clear()
     # flush tables built this run (prefetched or replay-built) so the
     # next warm run starts with every lookup already on disk
     if store is not None and digest is not None and \

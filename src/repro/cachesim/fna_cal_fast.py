@@ -22,13 +22,12 @@ The engine speculates and commits:
      it is patched one row at a time from verification verdicts instead
      of being rebuilt; while model views are still blended in,
      per-view-version tables are rebuilt from the frozen calibration
-     state, the whole (version x pattern) batch in one
-     ``repro.core.batched`` call (``selection_tables`` backend="numpy" /
-     ``exhaustive_tables`` — the same float64 math as the verification
-     pass, so a correct speculation always verifies).
+     state, the whole (version x pattern) batch in one call of the
+     verifier below — the same float64 math as the verification pass, so
+     a correct speculation always verifies.
   2. RECONSTRUCT the exact calibration-state trajectory the speculated
      probes imply: probe counts are integer cumsums; EWMA paths advance
-     per (cache, branch) through :func:`repro.core.estimator.ewma_path` —
+     per (cache, branch) through :func:`repro.core.estimator.ewma_paths` —
      the bit-identical scalar recurrence batched over the segment's probe
      events — and broadcast back per request.  Probe outcomes come free
      from the shared ``SystemTrace``: only the designated cache can hold
@@ -46,6 +45,15 @@ The engine speculates and commits:
      break-even (a flip burst), the engine drops into the scalar bridge
      for a stretch instead of thrashing table rebuilds.
 
+LANES: the cells of a decision-side group (a penalty or cost axis, or
+calibration settings) share one sweep, so their replays differ only in
+the settings each row is evaluated under.  :func:`fna_cal_lanes` runs
+them in lockstep, one lane per cell, each with its own state machine:
+one batched table build, one trajectory pass and one verifier call per
+round cover every lane, instead of one per lane.  ``run_cells`` hands a
+group's cells to it (``repro.cachesim.engine.FnaCalSegmented``); a lone
+replay is one lane.
+
 Bit-exactness: bridge-committed requests replicate the reference
 operations literally; speculatively-committed requests are verified
 equal to the float64 batched evaluation of the true rho (DS_PGM prefix
@@ -57,14 +65,15 @@ calibration settings.
 from __future__ import annotations
 
 import time
-from typing import List, Tuple
+from typing import List
 
 import numpy as np
 
 from repro import obs
 from repro.cachesim.systemstate import SystemTrace
-from repro.core.batched import rho_exhaustive_tables, rho_selection_tables
-from repro.core.estimator import ewma_path
+from repro.core.batched import (EXHAUSTIVE_CHUNK_ELEMS,
+                                rho_exhaustive_tables, rho_selection_tables)
+from repro.core.estimator import ewma_paths
 from repro.core.policies import ds_pgm_mask, exhaustive_mask
 
 _START_WINDOW = 512
@@ -81,103 +90,182 @@ _BRIDGE_LEN = 32             # scalar requests per bridge stretch
 _MAX_SEG_VERSIONS = 16
 
 
-def replay_fna_cal(sim, st: SystemTrace, res):
-    """Full fna_cal fast replay: committed selections + the shared fold."""
-    from repro.cachesim.fastpath import accumulate_replay
-    return accumulate_replay(res, st, fna_cal_selections(sim, st),
-                             [float(c) for c in sim.cfg.costs],
-                             float(sim.cfg.miss_penalty))
-
-
 def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
-    """[N] committed (post-exploration) selection bitmasks for fna_cal —
-    the speculate/verify/bridge engine described in the module docstring,
-    minus the cost fold.  Exposed separately so the topology layer can
-    re-account the same decisions under per-tier penalties.
+    """[N] committed (post-exploration) selection bitmasks for one
+    fna_cal cell: the lockstep engine of :func:`fna_cal_lanes` with one
+    lane.  Exposed separately so the topology layer can re-account the
+    same decisions under per-tier penalties."""
+    return fna_cal_lanes([sim.cfg], st)[0]
+
+
+class _Lane:
+    """One fna_cal cell's speculate/verify/bridge state machine against
+    the shared sweep.  Its calibration state is row ``i`` of the engine's
+    [R, 2n] state arrays."""
+
+    def __init__(self, i: int, cfg, eps_bits: np.ndarray, N: int):
+        self.i = i
+        self.costs = [float(c) for c in cfg.costs]
+        self.M = float(cfg.miss_penalty)
+        self.g = float(cfg.cal_gamma)
+        self.min_obs = int(cfg.cal_min_obs)
+        self.eps_bits = eps_bits
+        self.selm = np.empty(N, dtype=np.int64)   # committed (post-eps)
+        self.s = 0
+        self.window = _START_WINDOW
+        self.table = None       # steady-state (all-emp) speculation table
+        # the open segment: [s, s + L), of which ``commit`` rows committed;
+        # L == 0 while none is open
+        self.L = 0
+        self.commit = 0
+        self.all_emp = False
+        self.spec = self.sel_spec = None
+
+
+def fna_cal_lanes(cfgs, st: SystemTrace) -> List[np.ndarray]:
+    """[N] committed (post-exploration) selection bitmasks for each of
+    several fna_cal cells against ONE shared sweep, run in lockstep.
+
+    Each cell is a lane with its own copy of the speculate/verify/bridge
+    state machine described in the module docstring (position, window,
+    speculation table, calibration state); lanes may differ in costs,
+    miss penalty and every ``cal_*`` setting, and must share ``alg``,
+    which picks the verifier.  Each round opens a segment on every lane
+    without one (after its scalar bridge stretch, if a flip burst called
+    for one), builds the speculation tables of all of them in one batched
+    call, then reconstructs the exact-state trajectory of every open
+    segment's next chunk in one pass over the stacked rows and verifies
+    them all in one float64 call with per-row costs and penalties.  Each
+    lane then commits up to its own first mismatch and bridges it, as a
+    lone lane does.  Every row is evaluated row-locally, so each lane's
+    masks and counts are those of a run on its own.
 
     Adds to the ``fna_cal.*`` counters of :mod:`repro.obs` once per call:
-    ``requests`` (N), ``spec_committed`` (requests committed by
+    ``requests`` (N per lane), ``spec_committed`` (requests committed by
     verification), ``verified_rows`` (rows passed to the verifier, the
-    aborted chunk's included), ``bridged`` (requests replayed by the
-    scalar bridge; ``spec_committed + bridged == requests``), and the
-    nanoseconds of the table builds (``build_ns``), the exact-state
-    trajectories (``trajectory_ns``), the verifier and its compare
-    (``verify_ns``) and the bridge (``bridge_ns``)."""
-    cfg = sim.cfg
+    aborted chunks' included), ``bridged`` (requests replayed by the
+    scalar bridge; ``spec_committed + bridged == requests``), ``rounds``
+    (verifier calls), ``lane_chunks`` (lane chunks verified; equal to
+    ``rounds`` for one lane), and the nanoseconds of the table builds
+    (``build_ns``), the exact-state trajectories (``trajectory_ns``), the
+    verifier and its compare (``verify_ns``) and the bridge
+    (``bridge_ns``)."""
+    if len({cfg.alg for cfg in cfgs}) != 1:
+        raise ValueError("fna_cal lanes must share one alg")
     n = st.n
     N = st.trace_len
     k = 1 << n
-    costs = [float(c) for c in cfg.costs]
-    M = float(cfg.miss_penalty)
-    g = float(cfg.cal_gamma)
-    min_obs = int(cfg.cal_min_obs)
     # the speculate-and-commit loop is subroutine-agnostic: it needs a
     # scalar bitmask call (bridge/table rows) and a batched float64
     # verifier over an arbitrary rho matrix.  ds_pgm pairs the stripped
     # scalar variant with the prefix-scan verifier; exhaustive (n <= 12 —
     # the Simulator dispatch falls back to the reference loop beyond the
     # table budget) pairs it with the batched 2^n-subset enumeration.
-    if cfg.alg == "exhaustive":
-        mask_fn, verify_fn = exhaustive_mask, rho_exhaustive_tables
-    else:
-        mask_fn, verify_fn = ds_pgm_mask, rho_selection_tables
+    exhaustive = cfgs[0].alg == "exhaustive"
+    mask_fn = exhaustive_mask if exhaustive else ds_pgm_mask
     arange_n = np.arange(n)
     pow2 = (np.int64(1) << arange_n).astype(np.int64)
     bits_of = ((np.arange(k)[:, None] >> arange_n) & 1).astype(bool)  # [2^n, n]
 
-    # epsilon-exploration draws: the exact RNG stream of the reference loop
-    rng = np.random.default_rng(cfg.seed + 12345)
-    eps_draws = rng.random(N)
-    eps_pick = rng.integers(0, n, N)
-    eps_bits = np.where(eps_draws < cfg.cal_epsilon,
-                        np.int64(1) << eps_pick, np.int64(0))
-
     ver = st.ver_per_req
+    pats = st.pats
+    # per-request data of both branches, cache-major so a lane's chunk is
+    # a contiguous column slice: row j is the positive branch of cache j,
+    # row n + j its negative branch
+    ind2_t = np.concatenate([st.ind_all.T, ~st.ind_all.T])      # [2n, N]
     # probe outcome per (request, cache): only the designated cache can
     # hold a key, so absence is a pure function of the shared sweep
-    absent = np.ones((N, n), dtype=np.float64)
-    absent[np.arange(N), st.dj_all] = (~st.in_dj).astype(np.float64)
-    uninf_v = (st.fp_v + st.fn_v) >= 0.95           # [V, n]
+    abs2_t = np.ones((2 * n, N), dtype=np.float64)              # [2n, N]
+    abs2_t[st.dj_all, np.arange(N)] = (~st.in_dj).astype(np.float64)
+    abs2_t[n:] = abs2_t[:n]
+    bits2_t = np.concatenate([bits_of.T, bits_of.T])           # [2n, 2^n]
+    uninf_v = (st.fp_v + st.fn_v) >= 0.95                      # [V, n]
+    uninf2_t = np.concatenate([uninf_v.T, uninf_v.T])          # [2n, V]
+    model2_t = np.concatenate([st.pi_v.T, st.nu_v.T])          # [2n, V]
     # scalar-bridge views of the per-version data (python lists: the
     # bridge reads a handful of scalars per request)
     uninf_l = uninf_v.tolist()
     mpi_l = st.pi_v.tolist()
     mnu_l = st.nu_v.tolist()
 
-    # calibration state (optimistic init — see the reference loop)
-    pi_emp = np.full(n, 0.5, np.float64)
-    nu_emp = np.full(n, 0.90, np.float64)
-    pi_obs = np.zeros(n, np.int64)
-    nu_obs = np.zeros(n, np.int64)
+    # epsilon-exploration draws: the exact RNG stream of the reference loop
+    draws = {}
+    lanes = []
+    for i, cfg in enumerate(cfgs):
+        if cfg.seed not in draws:
+            rng = np.random.default_rng(cfg.seed + 12345)
+            draws[cfg.seed] = (rng.random(N), rng.integers(0, n, N))
+        eps_draws, eps_pick = draws[cfg.seed]
+        lanes.append(_Lane(i, cfg, np.where(
+            eps_draws < cfg.cal_epsilon, np.int64(1) << eps_pick,
+            np.int64(0)), N))
+    R = len(lanes)
+    costs_l = np.asarray([lane.costs for lane in lanes], np.float64)  # [R, n]
+    pen_l = np.asarray([lane.M for lane in lanes], np.float64)
+    gam_l = np.asarray([lane.g for lane in lanes], np.float64)
+    min_obs_l = np.asarray([lane.min_obs for lane in lanes], np.int64)
+    cost_groups: dict = {}
+    for lane in lanes:
+        cost_groups.setdefault(tuple(lane.costs), []).append(lane.i)
 
-    selm = np.empty(N, dtype=np.int64)      # committed (post-eps) masks
+    # calibration state, a row per lane: the EWMAs and probe counts of
+    # the positive branch (columns :n) and the negative one (n:)
+    # (optimistic init — see the reference loop)
+    emp = np.empty((R, 2 * n), np.float64)
+    emp[:, :n] = 0.5
+    emp[:, n:] = 0.90
+    obs_n = np.zeros((R, 2 * n), np.int64)
 
     clock = time.perf_counter_ns
     build_ns = trajectory_ns = verify_ns = bridge_ns = 0
-    spec_committed = verified_rows = bridged = 0
+    spec_committed = verified_rows = bridged = rounds = lane_chunks = 0
 
-    def bridge(s: int, count: int) -> Tuple[int, int]:
-        """Reference-exact scalar replay of ``count`` requests from ``s``:
-        per-request blend, scalar DS_PGM, exploration, probe feedback —
-        the literal reference operations over the precomputed system
-        arrays.  Mutates the calibration state in place; returns (end,
-        pre-exploration mask of the last request) — the fresh table row."""
-        nonlocal pi_emp, nu_emp, pi_obs, nu_obs, bridge_ns, bridged
+    def decide(rho: np.ndarray, ids: np.ndarray, sizes) -> np.ndarray:
+        """int64 masks of the rho rows of lanes ``ids``, ``sizes[a]``
+        consecutive rows each, under each lane's costs and penalty: the
+        float64 verifier, which also builds the speculation tables, so a
+        correct speculation always verifies.  The subset enumeration takes
+        one cost vector per call, so its rows go by cost group, in bounded
+        chunks as ``exhaustive_tables`` evaluates them."""
+        lane_of_row = np.repeat(ids, sizes)
+        pen = pen_l[lane_of_row]
+        if not exhaustive:
+            return rho_selection_tables(costs_l[lane_of_row], rho, pen) @ pow2
+        out = np.empty(rho.shape[0], np.int64)
+        step = max(1, EXHAUSTIVE_CHUNK_ELEMS // k)
+        for costs, members in cost_groups.items():
+            rows = np.flatnonzero(np.isin(lane_of_row, members))
+            for lo in range(0, rows.size, step):
+                r = rows[lo:lo + step]
+                out[r] = rho_exhaustive_tables(costs, rho[r], pen[r]) @ pow2
+        return out
+
+    def bridge(lane: _Lane, count: int) -> int:
+        """Reference-exact scalar replay of ``count`` requests from the
+        lane's position: per-request blend, scalar DS_PGM, exploration,
+        probe feedback — the literal reference operations over the
+        precomputed system arrays.  Mutates the lane's calibration state
+        and position; returns the pre-exploration mask of the last
+        request — the fresh table row."""
+        nonlocal bridge_ns, bridged
         t0 = clock()
+        i, s = lane.i, lane.s
         end = min(s + count, N)
-        pe: List[float] = pi_emp.tolist()
-        ne: List[float] = nu_emp.tolist()
-        po: List[int] = pi_obs.tolist()
-        no: List[int] = nu_obs.tolist()
-        pats_c = st.pats[s:end].tolist()
+        costs, M, g, min_obs = lane.costs, lane.M, lane.g, lane.min_obs
+        selm = lane.selm
+        pe: List[float] = emp[i, :n].tolist()
+        ne: List[float] = emp[i, n:].tolist()
+        po: List[int] = obs_n[i, :n].tolist()
+        no: List[int] = obs_n[i, n:].tolist()
+        pats_c = pats[s:end].tolist()
         ver_c = ver[s:end].tolist()
-        abs_c = absent[s:end].tolist()
-        eps_c = eps_bits[s:end].tolist()
+        abs_c = abs2_t[:n, s:end].T.tolist()
+        eps_c = lane.eps_bits[s:end].tolist()
         rng_n = range(n)
         base = 0
-        for i in range(end - s):
-            v = ver_c[i]
-            pat = pats_c[i]
+        for r in range(end - s):
+            v = ver_c[r]
+            pat = pats_c[r]
             uv = uninf_l[v]
             mp = mpi_l[v]
             mn = mnu_l[v]
@@ -187,9 +275,9 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
                 else (ne[j] if (no[j] >= min_obs or uv[j]) else mn[j])
                 for j in rng_n]
             base = mask_fn(costs, rhos, M)
-            m = base | eps_c[i]
-            selm[s + i] = m
-            ai = abs_c[i]
+            m = base | eps_c[r]
+            selm[s + r] = m
+            ai = abs_c[r]
             mm, j = m, 0
             while mm:
                 if mm & 1:
@@ -202,160 +290,181 @@ def fna_cal_selections(sim, st: SystemTrace) -> np.ndarray:
                         no[j] += 1
                 mm >>= 1
                 j += 1
-        pi_emp = np.asarray(pe, np.float64)
-        nu_emp = np.asarray(ne, np.float64)
-        pi_obs = np.asarray(po, np.int64)
-        nu_obs = np.asarray(no, np.int64)
+        emp[i] = pe + ne
+        obs_n[i] = po + no
+        lane.s = end
         bridged += end - s
         bridge_ns += clock() - t0
-        return end, base
+        return base
 
-    def build_tables(vids) -> dict:
-        """2^n speculation tables from the frozen calibration state, one
-        per view version — the whole (version x pattern) batch produced
-        by ONE ``repro.core.batched`` call (``selection_tables`` /
-        ``exhaustive_tables``) instead of 2^n scalar ``mask_fn`` calls
-        per version.  The batched float64 rows match ``verify_fn``'s math
-        exactly, so speculation quality only improves; exactness is still
-        owned by the verification pass and the scalar bridge."""
-        from repro.core.batched import exhaustive_tables, selection_tables
-        nonlocal build_ns
-        t0 = clock()
-        use_pi = pi_obs >= min_obs
-        use_nu = nu_obs >= min_obs
-        vids = [int(v) for v in vids]
-        rp = np.where(use_pi[None, :] | uninf_v[vids],
-                      pi_emp[None, :], st.pi_v[vids])          # [m, n]
-        rn = np.where(use_nu[None, :] | uninf_v[vids],
-                      nu_emp[None, :], st.nu_v[vids])
-        if cfg.alg == "exhaustive":
-            flat = exhaustive_tables(costs, rp, rn, M).reshape(-1)
-        else:
-            tab = selection_tables(costs, rp, rn, M, backend="numpy")
-            flat = (tab.reshape(-1, n) @ pow2).astype(np.int64)
-        build_ns += clock() - t0
-        return {v: flat[i * k:(i + 1) * k] for i, v in enumerate(vids)}
+    live = lanes if N else []
+    while live:
+        # --- 0. open a segment on every lane without one -----------------
+        builds = []             # (lane, sorted view versions to tabulate)
+        opened = []
+        for lane in live:
+            if lane.L:
+                continue
+            if lane.window < _SPEC_MIN_WINDOW:   # flip burst: scalar stretch
+                bridge(lane, _BRIDGE_LEN)
+                lane.window = _SPEC_MIN_WINDOW
+                lane.table = None                # state moved under the table
+                if lane.s >= N:
+                    continue
+            s = lane.s
+            L = min(lane.window, N - s)
+            lane.all_emp = bool((obs_n[lane.i] >= lane.min_obs).all())
+            if lane.all_emp:
+                if lane.table is None:
+                    builds.append((lane, ver[s:s + 1]))
+            else:
+                # model views in play: decisions are version-dependent, so
+                # use exact per-version tables and bound how many a segment
+                # builds
+                cut = int(np.searchsorted(ver, ver[s] + _MAX_SEG_VERSIONS,
+                                          side="left"))
+                L = max(min(L, cut - s), 1)
+                builds.append((lane, np.unique(ver[s:s + L])))
+            lane.L, lane.commit = L, 0
+            opened.append(lane)
 
-    s = 0
-    window = _START_WINDOW
-    table = None                # steady-state (all-emp) speculation table
-    while s < N:
-        if window < _SPEC_MIN_WINDOW:           # flip burst: scalar stretch
-            s, _ = bridge(s, _BRIDGE_LEN)
-            window = _SPEC_MIN_WINDOW
-            table = None                        # state moved under the table
-            continue
-        L = min(window, N - s)
-        all_emp = bool((pi_obs >= min_obs).all() and
-                       (nu_obs >= min_obs).all())
-        if not all_emp:
-            # model views in play: decisions are version-dependent, so use
-            # exact per-version tables and bound how many a segment builds
-            cut = int(np.searchsorted(ver, ver[s] + _MAX_SEG_VERSIONS,
-                                      side="left"))
-            L = max(min(L, cut - s), 1)
-        sl = slice(s, s + L)
-
-        # --- 1. speculate -------------------------------------------------
-        if all_emp:
-            if table is None:
-                table = build_tables([int(ver[s])])[int(ver[s])]
-            spec = table[st.pats[sl]]
-        else:
-            vseg = ver[sl]
-            tables = build_tables(np.unique(vseg).tolist())
-            spec = np.empty(L, np.int64)
-            for v, tab in tables.items():
-                vm = vseg == v
-                spec[vm] = tab[st.pats[sl][vm]]
-        sel_spec = spec | eps_bits[sl]
-
-        # --- 2+3. exact state trajectories + verification, chunk-wise -----
-        # (the state at a chunk's start is exact because every previous
-        # chunk committed in full; aborting at the first mismatching chunk
-        # bounds the work wasted past a mis-speculation)
-        commit = 0
-        clean = True
-        while commit < L and clean:
+        # --- 1. speculate: every lane's tables in one batched build --------
+        # (from the frozen calibration state; the rows are the verifier's
+        # float64 math, so speculation quality only improves — exactness
+        # is still owned by the verification pass and the scalar bridge)
+        tabs_of = {}
+        if builds:
             t0 = clock()
-            c1 = min(commit + _CHUNK, L)
-            cl = c1 - commit
-            rows = slice(s + commit, s + c1)
-            ind_seg = st.ind_all[rows]
-            sel_b = bits_of[sel_spec[commit:c1]]        # [cl, n]
-            pos_ev = sel_b & ind_seg                    # positive probes
-            neg_ev = sel_b & ~ind_seg
-            # probe counts BEFORE each request r (+1 row: after the chunk)
-            cs_p = np.zeros((cl + 1, n), np.int64)
-            cs_n = np.zeros((cl + 1, n), np.int64)
-            np.cumsum(pos_ev, axis=0, out=cs_p[1:])
-            np.cumsum(neg_ev, axis=0, out=cs_n[1:])
-            pi_t = np.empty((cl + 1, n), np.float64)
-            nu_t = np.empty((cl + 1, n), np.float64)
-            a_seg = absent[rows]
-            for j in range(n):
-                idx = np.flatnonzero(pos_ev[:, j])
-                if idx.size:
-                    seq = np.empty(idx.size + 1, np.float64)
-                    seq[0] = pi_emp[j]
-                    seq[1:] = ewma_path(pi_emp[j], a_seg[idx, j], g)
-                    pi_t[:, j] = seq[cs_p[:, j]]
-                else:
-                    pi_t[:, j] = pi_emp[j]
-                idx = np.flatnonzero(neg_ev[:, j])
-                if idx.size:
-                    seq = np.empty(idx.size + 1, np.float64)
-                    seq[0] = nu_emp[j]
-                    seq[1:] = ewma_path(nu_emp[j], a_seg[idx, j], g)
-                    nu_t[:, j] = seq[cs_n[:, j]]
-                else:
-                    nu_t[:, j] = nu_emp[j]
-            if all_emp:
-                rho = np.where(ind_seg, pi_t[:cl], nu_t[:cl])
+            rhos = []
+            for lane, vids in builds:
+                i = lane.i
+                use = (obs_n[i] >= lane.min_obs)[:, None] | uninf2_t[:, vids]
+                r2 = np.where(use, emp[i][:, None], model2_t[:, vids]).T
+                rhos.append(np.where(bits_of[None, :, :], r2[:, None, :n],
+                                     r2[:, None, n:]).reshape(-1, n))
+            tabs = decide(np.concatenate(rhos),
+                          np.asarray([lane.i for lane, _ in builds]),
+                          [vids.size * k for _, vids in builds])
+            lo = 0
+            for lane, vids in builds:
+                tabs_of[lane.i] = (vids, tabs[lo:lo + vids.size * k])
+                lo += vids.size * k
+            build_ns += clock() - t0
+        for lane in opened:
+            sl = slice(lane.s, lane.s + lane.L)
+            if lane.all_emp:
+                if lane.table is None:
+                    lane.table = tabs_of[lane.i][1].copy()
+                spec = lane.table[pats[sl]]
             else:
-                vc = vseg[commit:c1]
-                uninf_seg = uninf_v[vc]                 # [cl, n]
-                up_t = (pi_obs[None] + cs_p[:cl] >= min_obs) | uninf_seg
-                un_t = (nu_obs[None] + cs_n[:cl] >= min_obs) | uninf_seg
-                rho = np.where(ind_seg,
-                               np.where(up_t, pi_t[:cl], st.pi_v[vc]),
-                               np.where(un_t, nu_t[:cl], st.nu_v[vc]))
-            t1 = clock()
-            true_selm = verify_fn(costs, rho, M) @ pow2
-            bad = np.flatnonzero(true_selm != spec[commit:c1])
-            trajectory_ns += t1 - t0
-            verify_ns += clock() - t1
-            verified_rows += cl
-            ok = cl if bad.size == 0 else int(bad[0])
-            clean = bad.size == 0
+                vids, tab = tabs_of[lane.i]
+                spec = tab[np.searchsorted(vids, ver[sl]) * k + pats[sl]]
+            lane.spec = spec
+            lane.sel_spec = spec | lane.eps_bits[sl]
+
+        act = [lane for lane in live if lane.L]
+        if not act:
+            break
+        # --- 2. exact state trajectories of every open segment's next chunk
+        # (a lane's state at its chunk's start is exact because every
+        # previous chunk of its segment committed in full; aborting at the
+        # first mismatching chunk bounds the work wasted past a
+        # mis-speculation).  The chunks stack side by side: [2n, B].
+        t0 = clock()
+        ids = np.asarray([lane.i for lane in act])
+        A = ids.size
+        firsts = [lane.s + lane.commit for lane in act]
+        cls = [min(lane.commit + _CHUNK, lane.L) - lane.commit
+               for lane in act]
+        offs = np.zeros(A + 1, np.int64)
+        np.cumsum(cls, out=offs[1:])
+        B = int(offs[-1])
+        spec_c = np.concatenate([lane.spec[lane.commit:lane.commit + cl]
+                                 for lane, cl in zip(act, cls)])
+        sel_c = np.concatenate([lane.sel_spec[lane.commit:lane.commit + cl]
+                                for lane, cl in zip(act, cls)])
+        ind2 = np.concatenate([ind2_t[:, a:a + cl]
+                               for a, cl in zip(firsts, cls)], axis=1)
+        # probes: row j the positive ones of cache j, row n + j the negative
+        ev = bits2_t.take(sel_c, axis=1) & ind2
+        # probe counts before each stacked column (+1: after the stack); a
+        # lane's own counts are these less the counts at its first column
+        cs = np.zeros((2 * n, B + 1), np.int64)
+        np.cumsum(ev, axis=1, out=cs[:, 1:])
+        cs_o = cs[:, offs]                                  # [2n, A + 1]
+        # EWMA paths per (branch, cache, lane) in one pass: each row's
+        # probe outcomes in column order hold each lane's in turn
+        counts = np.diff(cs_o, axis=1).ravel()              # (row, lane)
+        seq = ewma_paths(
+            emp[ids].T.ravel(),
+            np.concatenate([abs2_t[:, a:a + cl]
+                            for a, cl in zip(firsts, cls)], axis=1)[ev],
+            counts, np.tile(gam_l[ids], 2 * n))
+        # where each path starts in ``seq``, less the stacked counts before
+        # its lane's first column: + cs indexes the EWMA before a column
+        start = np.zeros(counts.size, np.int64)
+        np.cumsum(counts[:-1] + 1, out=start[1:])
+        base = start.reshape(2 * n, A) - cs_o[:, :-1]      # [2n, A]
+        ewm = seq.take(np.repeat(base, cls, axis=1) + cs[:, :-1])
+        if not all(lane.all_emp for lane in act):
+            # model views blend in until a branch has min-obs probes (an
+            # all-empirical lane's counts are past it: it reads the EWMA)
+            vc = np.concatenate([ver[a:a + cl] for a, cl in zip(firsts, cls)])
+            seen = np.repeat(obs_n[ids].T - cs_o[:, :-1], cls, axis=1) \
+                + cs[:, :-1]
+            use = (seen >= np.repeat(min_obs_l[ids], cls)) | \
+                uninf2_t.take(vc, axis=1)
+            ewm = np.where(use, ewm, model2_t.take(vc, axis=1))
+        rho = np.ascontiguousarray(np.where(ind2[:n], ewm[:n], ewm[n:]).T)
+        t1 = clock()
+        # --- 3. verify every lane's chunk in one call ---------------------
+        bad = np.flatnonzero(decide(rho, ids, cls) != spec_c)
+        # each lane's first mismatching stacked row (B: none)
+        first = np.append(bad, B)[np.searchsorted(bad, offs[:-1])]
+        ends = np.minimum(first, offs[1:])
+        trajectory_ns += t1 - t0
+        verify_ns += clock() - t1
+        rounds += 1
+        lane_chunks += A
+        verified_rows += B
+        # each lane's state after its committed rows
+        cs_e = cs[:, ends]
+        emp[ids] = seq.take(base + cs_e).T
+        obs_n[ids] += (cs_e - cs_o[:, :-1]).T
+
+        # --- 4. commit and adapt, lane by lane ---------------------------
+        for lane, ok, clean in zip(act, (ends - offs[:-1]).tolist(),
+                                   (first >= offs[1:]).tolist()):
+            c0 = lane.commit
             spec_committed += ok
-            selm[s + commit:s + commit + ok] = sel_spec[commit:commit + ok]
-            pi_emp = pi_t[ok].copy()
-            nu_emp = nu_t[ok].copy()
-            pi_obs = pi_obs + cs_p[ok]
-            nu_obs = nu_obs + cs_n[ok]
-            commit += ok
-
-        # --- 4. adapt ------------------------------------------------------
-        s += commit
-        if clean:
-            window = min(window * 2, _MAX_WINDOW)
-        else:
-            # replay the mismatched request itself scalar-exactly; its
-            # fresh decision patches the (speculation-only) table row
-            pat = int(st.pats[s])
-            s, row = bridge(s, 1)
-            if all_emp and table is not None:
-                table[pat] = row
+            lane.selm[lane.s + c0:lane.s + c0 + ok] = \
+                lane.sel_spec[c0:c0 + ok]
+            lane.commit = commit = c0 + ok
+            if clean and commit < lane.L:
+                continue                         # the segment goes on
+            lane.s += commit
+            lane.L = 0
+            if clean:
+                lane.window = min(lane.window * 2, _MAX_WINDOW)
             else:
-                table = None
-            window = 0 if commit < _BURST_COMMIT \
-                else min(max(2 * commit, _SPEC_MIN_WINDOW), _MAX_WINDOW)
+                # replay the mismatched request itself scalar-exactly; its
+                # fresh decision patches the (speculation-only) table row
+                pat = int(pats[lane.s])
+                row = bridge(lane, 1)
+                if lane.all_emp and lane.table is not None:
+                    lane.table[pat] = row
+                else:
+                    lane.table = None
+                lane.window = 0 if commit < _BURST_COMMIT \
+                    else min(max(2 * commit, _SPEC_MIN_WINDOW), _MAX_WINDOW)
+        live = [lane for lane in live if lane.s < N]
 
-    for name, value in (("requests", N), ("spec_committed", spec_committed),
+    for name, value in (("requests", N * R),
+                        ("spec_committed", spec_committed),
                         ("verified_rows", verified_rows),
-                        ("bridged", bridged), ("build_ns", build_ns),
+                        ("bridged", bridged), ("rounds", rounds),
+                        ("lane_chunks", lane_chunks), ("build_ns", build_ns),
                         ("trajectory_ns", trajectory_ns),
                         ("verify_ns", verify_ns), ("bridge_ns", bridge_ns)):
         obs.add(f"fna_cal.{name}", value)
-    return selm
+    return [lane.selm for lane in lanes]

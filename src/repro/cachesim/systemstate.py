@@ -364,6 +364,12 @@ class SystemTrace:
     # ``repro.cachesim.engine`` and by the sweep runner's stacked
     # cross-cell prefetch, read back at replay time
     plan_cache: Dict[tuple, np.ndarray] = field(default_factory=dict)
+    # the lockstep fna_cal hand-over of one ``run_cells`` group (see
+    # ``repro.cachesim.engine.FnaCalSegmented``): the group's fna_cal cells
+    # not yet replayed, and the selections their shared replay left for the
+    # later cells — never stored, unlike ``plan_cache``
+    fna_cal_lanes: Dict[tuple, object] = field(default_factory=dict)
+    fna_cal_selections: Dict[tuple, np.ndarray] = field(default_factory=dict)
     # forwarded-stream positions (see forward_positions); None = derive
     _fwd_pos: Optional[np.ndarray] = None
 

@@ -73,25 +73,38 @@ class QEstimator:
         return self.q
 
 
-def ewma_path(e0: float, outcomes: np.ndarray, gamma: float) -> np.ndarray:
-    """Exact trajectory of the probe-feedback EWMA ``e <- (1-g)e + g a``.
+def ewma_paths(e0s, outcomes, counts, gammas) -> np.ndarray:
+    """Exact trajectories of the probe-feedback EWMA ``e <- (1-g)e + g a``,
+    several in one pass.
 
-    ``outcomes`` are the {0, 1} probe results in arrival order; returns the
-    value AFTER each update, as float64.  The recurrence is applied one
-    scalar IEEE multiply-add at a time — i.e. it IS the reference loop's
-    update, so the returned path is bit-identical to updating per probe
-    (unlike an ``exp/cumsum`` closed form, whose rounding differs).  The
-    simulator's calibrated fast engine uses this to advance a whole
-    speculation segment's EWMA state in one call per (cache, branch).
+    Trajectory i starts at ``e0s[i]`` and takes the next ``counts[i]`` of
+    the {0, 1} probe results ``outcomes`` (in arrival order) with gain
+    ``gammas[i]``.  Returns, trajectory by trajectory, its start value
+    followed by the value AFTER each update (``len(outcomes) + len(e0s)``
+    float64 values).  The recurrence is applied one scalar IEEE multiply
+    and add at a time — i.e. it IS the reference loop's update, so every
+    path is bit-identical to updating per probe (unlike an
+    ``exp/cumsum`` closed form, whose rounding differs).  The simulator's
+    calibrated fast engine uses this to advance every (lane, cache,
+    branch) EWMA of a round's chunks in one call.
     """
-    a = np.asarray(outcomes, dtype=np.float64)
-    out = np.empty(a.shape[0], dtype=np.float64)
-    e = float(e0)
-    g = float(gamma)
-    for t, av in enumerate(a.tolist()):
-        e = (1.0 - g) * e + g * av
-        out[t] = e
-    return out
+    counts = np.asarray(counts, np.int64)
+    gammas = np.asarray(gammas, np.float64)
+    # g * a, one IEEE multiply per probe as in the update itself
+    ga = (np.repeat(gammas, counts) *
+          np.asarray(outcomes, dtype=np.float64)).tolist()
+    out: list = []
+    append = out.append
+    lo = 0
+    for e, c, g in zip(np.asarray(e0s, np.float64).tolist(),
+                       counts.tolist(), gammas.tolist()):
+        append(e)
+        keep = 1.0 - g
+        for x in ga[lo:lo + c]:
+            e = keep * e + x
+            append(e)
+        lo += c
+    return np.fromiter(out, np.float64, len(out))
 
 
 class WindowedRatio:

@@ -178,16 +178,121 @@ def test_run_sweep_grid_matches_reference():
     assert {r["update_interval"] for r in recs} == {100, 800}
 
 
+LANE_COUNTS = ("requests", "spec_committed", "verified_rows", "bridged")
+
+
+def _counted(fn):
+    """``fn()`` and the ``fna_cal.*`` counts it added."""
+    from repro import obs
+    before = obs.counters()
+    out = fn()
+    after = obs.counters()
+    return out, {c: after.get(f"fna_cal.{c}", 0) -
+                 before.get(f"fna_cal.{c}", 0)
+                 for c in LANE_COUNTS + ("rounds", "lane_chunks")}
+
+
+@pytest.mark.parametrize("n_req, alg, lanes", [
+    (6_000, "ds_pgm", [dict(miss_penalty=p) for p in (25.0, 100.0, 1000.0)]),
+    (3_000, "exhaustive", [dict(miss_penalty=p) for p in (25.0, 250.0)]),
+    (3_000, "exhaustive", [dict(costs=(1.0, 2.0, 3.0)),
+                           dict(costs=(2.0, 2.0, 2.0)),
+                           dict(costs=(1.0, 2.0, 3.0), miss_penalty=500.0)]),
+    (6_000, "ds_pgm", [dict(costs=(1.0, 2.0, 3.0)),
+                       dict(costs=(3.0, 1.0, 2.0), miss_penalty=40.0),
+                       dict(costs=(2.0, 2.0, 2.0), miss_penalty=400.0)]),
+    (6_000, "ds_pgm", [dict(cal_min_obs=5), dict(cal_gamma=0.2),
+                       dict(cal_epsilon=0.05), dict(cal_epsilon=0.0)]),
+    (6_000, "ds_pgm", [dict(miss_penalty=25.0), dict(miss_penalty=1000.0)]),
+    (1_500, "ds_pgm", [dict(cal_min_obs=1_000_000), dict(),
+                       dict(miss_penalty=500.0)]),
+], ids=["penalties", "exhaustive_penalties", "exhaustive_costs", "costs",
+        "calibration", "far_apart", "model_view_lane"])
+def test_lockstep_lanes_equal_lone_lanes(n_req, alg, lanes):
+    """The lockstep engine over R lanes equals R lone runs bit for bit:
+    every lane's masks, and the summed counts — whatever the lanes differ
+    in, however many rounds apart they finish, and with a lane that never
+    leaves the model-view blend."""
+    from repro.cachesim.fna_cal_fast import fna_cal_lanes, fna_cal_selections
+    trace = get_trace("gradle", n_req, seed=3)
+    base = SimConfig(cache_size=500, policy="fna_cal", alg=alg,
+                     update_interval=200, est_interval=25)
+    donor = Simulator(base)
+    donor.run(trace)
+    st = donor.last_system
+    cfgs = [dataclasses.replace(base, **kw) for kw in lanes]
+    alone, alone_n = _counted(lambda: [
+        fna_cal_selections(Simulator(cfg), st) for cfg in cfgs])
+    together, together_n = _counted(lambda: fna_cal_lanes(cfgs, st))
+    for a, t in zip(alone, together):
+        assert np.array_equal(a, t)
+    assert {c: together_n[c] for c in LANE_COUNTS} == \
+        {c: alone_n[c] for c in LANE_COUNTS}
+    assert together_n["spec_committed"] + together_n["bridged"] == \
+        len(cfgs) * n_req
+    assert alone_n["rounds"] == alone_n["lane_chunks"] == \
+        together_n["lane_chunks"] > together_n["rounds"]
+
+
+def test_run_cells_lockstep_group_matches_reference(tmp_path, monkeypatch):
+    """A ``run_cells`` group with several fna_cal cells replays them in
+    lockstep, gives the reference engine's rows, and stores the group's
+    decision tables and nothing else: no selections, no hand-over."""
+    from repro.cachesim.engine import (FnaCalSegmented, run_cells,
+                                       table_keys_for)
+    from repro.cachesim.store import ArtifactStore
+    trace = get_trace("gradle", 4_000, seed=6)
+    base = SimConfig(cache_size=500, update_interval=200, est_interval=25)
+    cfgs = [dataclasses.replace(base, miss_penalty=p)
+            for p in (25.0, 100.0, 1000.0)]
+    policies = ("fna", "fna_cal", "hocs")
+    seen = []
+    lone = FnaCalSegmented.selections
+
+    def spy(self, sim, st):
+        seen.append((len(st.fna_cal_lanes), len(st.fna_cal_selections)))
+        return lone(self, sim, st)
+
+    monkeypatch.setattr(FnaCalSegmented, "selections", spy)
+    store = ArtifactStore(tmp_path)
+    out = run_cells(trace, cfgs, policies, store=store)
+    # the first cell finds all three pending and runs them; the others
+    # take what it left
+    assert seen == [(3, 0), (0, 2), (0, 1)]
+    for cfg, cell in zip(cfgs, out):
+        for p in policies:
+            ref = Simulator(dataclasses.replace(
+                cfg, policy=p, engine="reference")).run(trace)
+            _assert_results_identical(ref, cell[p])
+    tables = [e for e in store.entries() if e[1] == "tables"]
+    assert len(tables) == len(table_keys_for(cfgs, policies)) == 6
+    digest = store.trace_digest(trace)
+    sys_key = SystemTrace.system_key(base)
+    for cfg in cfgs:
+        key = FnaCalSegmented.lane_key(cfg)
+        assert store.load_table(digest, sys_key, key) is None
+    st = store.load_sweep(trace, sys_key)
+    assert not st.fna_cal_lanes and not st.fna_cal_selections
+
+
 def test_ewma_path_matches_scalar_recurrence():
-    from repro.core.estimator import ewma_path
+    """Several trajectories in one pass, empty ones included, each
+    bit-identical to the per-probe scalar recurrence."""
+    from repro.core.estimator import ewma_paths
     rng = np.random.default_rng(0)
-    outcomes = (rng.random(500) < 0.4).astype(np.float64)
-    g = 0.05
-    e = 0.9
-    path = ewma_path(e, outcomes, g)
-    for t, a in enumerate(outcomes.tolist()):
-        e = (1 - g) * e + g * a
-        assert path[t] == e    # bit-identical, not approximately
+    counts = [500, 0, 37, 1, 0, 120]
+    gammas = [0.05, 0.2, 0.05, 0.5, 0.01, 0.3]
+    e0s = [0.9, 0.5, 0.1, 0.77, 0.3, 0.42]
+    outcomes = (rng.random(sum(counts)) < 0.4).astype(np.float64)
+    path = ewma_paths(e0s, outcomes, counts, gammas)
+    want, lo = [], 0
+    for e, c, g in zip(e0s, counts, gammas):
+        want.append(e)
+        for a in outcomes[lo:lo + c].tolist():
+            e = (1 - g) * e + g * a
+            want.append(e)
+        lo += c
+    assert path.tolist() == want    # bit-identical, not approximately
 
 
 def test_rho_selection_tables_matches_scalar_and_jax():

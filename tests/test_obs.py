@@ -60,6 +60,34 @@ def test_fna_cal_counters_add_up(cfg_kw):
         assert c[name] > 0, name
 
 
+def test_lone_replay_one_lane_per_round():
+    c = _fna_cal_counters(get_trace("gradle", N, seed=3),
+                          update_interval=200, est_interval=25)
+    assert c["rounds"] == c["lane_chunks"] > 0
+
+
+def test_penalty_group_runs_in_lockstep():
+    """An 8-cell penalty grid replays its fna_cal cells together: more than
+    one lane per verifier call, the same counts as the cells run alone."""
+    trace = get_trace("gradle", 4_000, seed=3)
+    base = SimConfig(cache_size=1_000, update_interval=200, est_interval=25)
+    penalties = [25.0, 50.0, 75.0, 100.0, 150.0, 250.0, 500.0, 1000.0]
+    run_grid({"gradle": trace}, base, axis="miss_penalty", values=penalties,
+             policies=("fna", "fna_cal"))
+    group = {k[len("fna_cal."):]: v for k, v in obs.counters().items()}
+    obs.reset()
+    for m in penalties:
+        Simulator(SimConfig(cache_size=1_000, update_interval=200,
+                            est_interval=25, miss_penalty=m,
+                            policy="fna_cal")).run(trace)
+    alone = {k[len("fna_cal."):]: v for k, v in obs.counters().items()}
+    assert group["lane_chunks"] / group["rounds"] > 1
+    assert alone["lane_chunks"] == alone["rounds"] == group["lane_chunks"]
+    assert group["requests"] == len(penalties) * 4_000
+    assert group["spec_committed"] + group["bridged"] == group["requests"]
+    assert {k: group[k] for k in COUNTS} == {k: alone[k] for k in COUNTS}
+
+
 def test_fna_cal_counts_repeat():
     trace = get_trace("gradle", N, seed=5)
     first = _fna_cal_counters(trace, update_interval=200, est_interval=25)
