@@ -17,6 +17,9 @@ call over the cell's trace: phase 1 (the system sweep) on the host,
 phase 2 (the stacked DS_PGM tables) on the chip, phase 3 (the replays)
 on the host.  Set-up makes the trace from ``--seed`` and runs a job's
 sweep and table build, which compiles the job's one device program.
+Set-up must end within :data:`SETUP_LIMIT_S` of the process's start: a
+run that overruns it ends there with exit code 1, the stacks of its
+threads on standard error and no result line.
 The window then runs jobs back to back until ``--seconds`` have passed
 and finishes the job in flight.  Each job must compute its own sweeps
 (``SWEEPS_COMPUTED`` rises by one per system group of the grid) and
@@ -29,6 +32,8 @@ compared, each beside its limit, come last in it and on standard error.
 """
 from __future__ import annotations
 
+import contextlib
+import faulthandler
 import importlib
 import json
 import os
@@ -60,6 +65,11 @@ WINDOW_SPAN = "bench.window"
 TABLES_MODULE = "jit__cells_tables_kernel"
 #: the exact comparison: rows that differ from the reference
 ROWS_LIMIT = 0
+#: seconds from the process's start by which set-up must end.  A run has
+#: six minutes; this leaves the rest to the window, the job in flight,
+#: the reference and the trace's reduction.  The slowest sound set-up,
+#: a cold compile of ``secv3.full``, takes about 43 s.
+SETUP_LIMIT_S = 150
 
 
 def log(msg: str) -> None:
@@ -319,13 +329,36 @@ def host_rss_bytes() -> int:
     raise RuntimeError("no VmRSS in /proc/self/status")
 
 
+@contextlib.contextmanager
+def setup_deadline(started: float, limit: Optional[float]):
+    """End the process, with exit code 1 and every thread's stack on
+    standard error, if the block is still running ``limit`` seconds
+    after ``started`` (``time.perf_counter``).  A compile is one long
+    native call that holds the interpreter lock, so a signal handler or
+    a Python timer could not run before it returns; ``faulthandler``'s
+    watchdog is a native thread.  ``None`` arms nothing."""
+    if limit is None:
+        yield
+        return
+    log(f"bench: set-up must end within {limit:g} s of the start")
+    remaining = limit - (time.perf_counter() - started)
+    faulthandler.dump_traceback_later(max(remaining, 1e-3), exit=True)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              started: float, require_tpu: bool = True,
              requests: Optional[int] = None,
-             ref_workers: Optional[int] = None) -> dict:
+             ref_workers: Optional[int] = None,
+             setup_limit: Optional[float] = None) -> dict:
     """One run of one cell; returns the result object.  ``started`` is
     the process's start on the ``time.perf_counter`` clock;
-    ``requests`` overrides the trace length (tests only)."""
+    ``requests`` overrides the trace length (tests only);
+    ``setup_limit`` arms :func:`setup_deadline` over set-up (the
+    command line's run only: the watchdog is one per process)."""
     import gc
 
     import jax
@@ -341,18 +374,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         f"({device['platform']}), seed {seed}; "
         f"{time.perf_counter() - started:.3f} s in, host RSS "
         f"{runtime_rss / 1e6:.3f} MB")
-    tr = make_trace(cell.mix, cell.requests, seed,
-                    residues=int(cell.system["n_caches"]))
     n_rows = len(cell.values) * len(cell.policies)
-    log(f"bench: trace made {time.perf_counter() - started:.3f} s in")
 
     # set-up: a job's sweep and table build compile what the window runs
     spans = Spans() if trace else None
     if spans is not None:
         spans.__enter__()
     try:
-        with CompileCounter() as warm:
-            sweeps_per_job = warm_up(cell, tr)
+        with setup_deadline(started, setup_limit):
+            tr = make_trace(cell.mix, cell.requests, seed,
+                            residues=int(cell.system["n_caches"]))
+            log(f"bench: trace made {time.perf_counter() - started:.3f} "
+                f"s in")
+            with CompileCounter() as warm:
+                sweeps_per_job = warm_up(cell, tr)
         setup_s = time.perf_counter() - started
         log(f"bench: set-up {setup_s:.3f} s ({warm.count} compiles, "
             f"{warm.seconds:.3f} s), {sweeps_per_job} sweep(s) per job, "
@@ -505,6 +540,7 @@ def main(argv=None, started: Optional[float] = None) -> int:
     sys.path.insert(0, str(ROOT / "src"))
     log(f"bench: compile cache {enable_compile_cache()}")
     result = run_cell(args.workload, args.seed, args.seconds,
-                      bool(args.trace), started=started)
+                      bool(args.trace), started=started,
+                      setup_limit=SETUP_LIMIT_S)
     print(json.dumps(result), flush=True)
     return 0

@@ -7,16 +7,15 @@ import pytest
 
 from bench import harness
 from bench.control import readings
+from bench.tests.sizes import cell_sizes
 
-
-#: the least trace length at which each cell's fleet probes
-REQUESTS = {"secv3.full": 3000}
 WORKLOADS = [w["name"] for w in harness.load_manifest()["workloads"]]
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_float32_control_is_not_correct(workload):
     (line,) = readings(workload, [1], 1, require_tpu=False,
-                       requests=REQUESTS[workload], ref_workers=0)
+                       requests=cell_sizes(workload)["control"],
+                       ref_workers=0)
     assert line["program_rows_off"] == 0
     assert line["control_rows_off"] > 0

@@ -12,16 +12,16 @@ import numpy as np
 import pytest
 
 from bench import harness
+from bench.tests.sizes import cell_sizes
 
-#: trace lengths at which each cell's fleet advertises and probes
-REQUESTS = {"secv3.full": 2000}
 WORKLOADS = tuple(w["name"] for w in harness.load_manifest()["workloads"])
 
 
 def run(workload, seed=5):
     return harness.run_cell(workload, seed, 0.0, False,
                             started=time.perf_counter(), require_tpu=False,
-                            requests=REQUESTS[workload], ref_workers=0)
+                            requests=cell_sizes(workload)["correct"],
+                            ref_workers=0)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -38,7 +38,7 @@ def test_warm_up_leaves_nothing_to_compile():
     the sweeps a job computes."""
     from bench.gen import make_trace
     cell = harness.resolve(harness.load_manifest(), "secv3.full")
-    cell.requests = REQUESTS["secv3.full"]
+    cell.requests = cell_sizes("secv3.full")["correct"]
     tr = make_trace(cell.mix, cell.requests, 5,
                     residues=int(cell.system["n_caches"]))
     sweeps = harness.warm_up(cell, tr)
@@ -132,7 +132,8 @@ def test_reused_sweep_fails_the_job(monkeypatch):
     _sweep_reused(monkeypatch)
     out = harness.run_cell("secv3.full", 5, 0.5, False,
                            started=time.perf_counter(), require_tpu=False,
-                           requests=REQUESTS["secv3.full"], ref_workers=0)
+                           requests=cell_sizes("secv3.full")["correct"],
+                           ref_workers=0)
     assert out["attempted"] >= 1 and out["failed"] == out["attempted"]
     assert out["compared"]["sweep_or_compile_faults"]["value"] == \
         out["attempted"]
